@@ -28,6 +28,7 @@ from repro.core.compressed import (pack_expert_stack, pack_linear,
                                    quantize_linear)
 from repro.core.policy import CompressionPolicy
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.kernels.fused_decode_matmul import DEFAULT_BM
 from repro.serve.context import ServeContext
 from repro.serve.engine import build_serve_params, generate
@@ -158,7 +159,7 @@ def sharded_fused_latency(rows: list | None = None):
         return
     msize = min(4, ndev)
     dsize = ndev // msize
-    mesh = jax.make_mesh((dsize, msize), ("data", "model"))
+    mesh = make_mesh((dsize, msize), ("data", "model"))
     rng = np.random.default_rng(0)
     m, size = 256, 1024
     n = k = size

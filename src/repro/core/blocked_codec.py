@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .codec import ESCAPE, DEFAULT_SEQ_LEN
+from .codec import ESCAPE, DEFAULT_SEQ_LEN, as_index
 
 DEFAULT_BLOCK_WEIGHTS = 4096
 
@@ -78,11 +78,12 @@ def build_lut(table: dict, seq_len: int = DEFAULT_SEQ_LEN) -> np.ndarray:
     return lut
 
 
-def encode_blocked(weights: np.ndarray, table: dict,
+def encode_blocked(weights: np.ndarray, table,
                    lut: np.ndarray | None = None,
                    block_weights: int = DEFAULT_BLOCK_WEIGHTS,
                    seq_len: int = DEFAULT_SEQ_LEN) -> BlockedCompressed:
-    """Encode a uint8 tensor into the blocked format (host-side numpy)."""
+    """Encode a uint8 tensor into the blocked format (host-side numpy).
+    ``table`` is the {gram: code} dict or a prebuilt ``codec.GramIndex``."""
     assert block_weights % seq_len == 0
     flat = np.ascontiguousarray(weights).reshape(-1).astype(np.uint8)
     orig_len = flat.size
@@ -94,19 +95,8 @@ def encode_blocked(weights: np.ndarray, table: dict,
     grams = flat.reshape(-1, seq_len)
     n_blocks = len(grams) // slots_pb
 
-    # Vectorized table lookup via packed uint keys.
-    keys = grams.astype(np.uint64)
-    packed = np.zeros(len(grams), np.uint64)
-    for j in range(seq_len):
-        packed = (packed << np.uint64(8)) | keys[:, j]
-    klut = {}
-    for seq, code in table.items():
-        k = 0
-        for v in seq:
-            k = (k << 8) | int(v)
-        klut[k] = code
-    codes_flat = np.array([klut.get(int(k), ESCAPE) for k in packed],
-                          dtype=np.uint16)
+    index = as_index(table, seq_len)
+    codes_flat = index.lookup(grams, ESCAPE).astype(np.uint16)
 
     codes = codes_flat.reshape(n_blocks, slots_pb)
     esc = codes == ESCAPE
@@ -114,12 +104,14 @@ def encode_blocked(weights: np.ndarray, table: dict,
     lit_cap = int(nlit.max()) if n_blocks else 0
     lit_cap = max(lit_cap, 1)
     literals = np.zeros((n_blocks, lit_cap, seq_len), dtype=np.uint8)
-    grams_b = grams.reshape(n_blocks, slots_pb, seq_len)
-    for b in np.nonzero(nlit)[0]:
-        literals[b, : nlit[b]] = grams_b[b][esc[b]]
+    # each escape's row is its rank among its block's escapes
+    rank = np.cumsum(esc, axis=1) - 1
+    blk, slot = np.nonzero(esc)
+    literals[blk, rank[blk, slot]] = grams.reshape(
+        n_blocks, slots_pb, seq_len)[blk, slot]
 
     if lut is None:
-        lut = build_lut(table, seq_len)
+        lut = build_lut(index.table, seq_len)
     return BlockedCompressed(
         codes=jnp.asarray(codes), literals=jnp.asarray(literals),
         nlit=jnp.asarray(nlit), lut=jnp.asarray(lut),
@@ -185,22 +177,43 @@ def blocked_nbytes(bc: BlockedCompressed, include_lut: bool = False) -> int:
 
 DEFAULT_TILE_N = 128   # matches dequant_matmul.DEFAULT_BN
 DEFAULT_TILE_K = 512   # matches dequant_matmul.DEFAULT_BK
+LANES = 128
+SUBLANES = 8
+# Largest whole-dim tile taken when no 128-aligned divisor exists (e.g.
+# deepseek's 576-row wkv_a): the decoded int32 tile must fit in VMEM.
+MAX_TILE_WEIGHTS = 1 << 19
 
 
 def _pow2_divisor(n: int, cap: int) -> int:
-    """Largest power of two that divides ``n``, capped at ``cap``."""
+    """Largest power of two that divides ``n`` and is ≤ ``cap``."""
     d = n & (-n)  # largest power-of-2 factor
-    return min(d, cap)
+    return min(d, 1 << (cap.bit_length() - 1))
 
 
-def _shrink_block_weights(vol: int, block_weights: int, seq_len: int) -> int:
-    """Halve a tile's volume down toward the ``block_weights`` cap while it
-    stays a whole number of ``seq_len`` grams — the single source of truth
-    for the fused layout's actual block size."""
-    bw = vol
-    while bw > block_weights and bw % 2 == 0 and (bw // 2) % seq_len == 0:
-        bw //= 2
-    return bw
+def _lane_tile(dim: int, cap: int) -> int:
+    """A tile of ``dim`` the TPU kernel can block: the largest power-of-two
+    divisor up to ``cap`` when that is a whole number of 128-lane vregs,
+    else the whole dim (a block may always span its array's full dim)."""
+    t = _pow2_divisor(dim, cap)
+    return t if t % LANES == 0 or t == dim else dim
+
+
+def fused_block_weights(tile_n: int, tile_k: int,
+                        block_weights: int = DEFAULT_BLOCK_WEIGHTS,
+                        seq_len: int = DEFAULT_SEQ_LEN):
+    """Block size of the fused layout for a (tile_n, tile_k) tile: whole
+    weight rows of the tile (``tile_k`` times a power of two dividing
+    ``tile_n``), up to the ``block_weights`` cap, and small enough that a
+    tile holds a multiple of 8 blocks where it can (the kernel blocks the
+    planes by whole sublane groups) — the single source of truth for the
+    layout's actual block size.  None when a row is not a whole number of
+    ``seq_len`` grams."""
+    if tile_k % seq_len:
+        return None
+    r = _pow2_divisor(tile_n, max(1, block_weights // tile_k))
+    while r > 1 and (tile_n // r) % SUBLANES:
+        r //= 2
+    return tile_k * r
 
 
 def choose_fused_tiles(shape: tuple, block_weights: int = DEFAULT_BLOCK_WEIGHTS,
@@ -210,12 +223,15 @@ def choose_fused_tiles(shape: tuple, block_weights: int = DEFAULT_BLOCK_WEIGHTS,
                        shards: tuple = (1, 1)):
     """Pick (tile_n, tile_k, block_weights) for the fused-kernel layout.
 
-    Tiles are the largest power-of-two divisors of (N, K) up to the kernel's
-    default matmul block — divisors, not round-ups, so no padding is ever
-    needed and decoded bytes are bit-identical to the linear layout's.
-    Returns None when the tensor cannot host a tile of at least one
-    ``seq_len`` gram (fused layout unavailable; callers fall back to the
-    linear layout + two-step path).
+    Tiles are divisors of (N, K) — never round-ups, so no padding is ever
+    needed and decoded bytes are bit-identical to the linear layout's —
+    that the TPU kernel can block: 128-lane multiples up to the kernel's
+    default matmul block, or the whole dim (see :func:`_lane_tile`).  A
+    narrow ``tile_k`` grows ``tile_n`` toward the default tile volume so a
+    tile still holds a sublane-aligned number of blocks.  Returns None when
+    no such tile fits ``MAX_TILE_WEIGHTS`` or holds whole grams (fused
+    layout unavailable; ``engine.build_serve_params`` then stores the
+    weight quant-only).
 
     ``shards=(sn, sk)``: intended mesh sharding of the dense dims.  Tiles
     are chosen to divide the *per-shard* dims (n/sn, k/sk) so the
@@ -233,13 +249,12 @@ def choose_fused_tiles(shape: tuple, block_weights: int = DEFAULT_BLOCK_WEIGHTS,
         n //= sn
     if sk > 1 and k % sk == 0:
         k //= sk
-    tn = _pow2_divisor(n, max_tile_n)
-    tk = _pow2_divisor(k, max_tile_k)
-    vol = tn * tk
-    if vol % seq_len:
+    tk = _lane_tile(k, max_tile_k)
+    tn = _lane_tile(n, max(max_tile_n, max_tile_n * max_tile_k // tk))
+    if tn * tk > MAX_TILE_WEIGHTS:
         return None
-    bw = _shrink_block_weights(vol, block_weights, seq_len)
-    if vol % bw or bw % seq_len:
+    bw = fused_block_weights(tn, tk, block_weights, seq_len)
+    if bw is None:
         return None
     return tn, tk, bw
 
@@ -263,7 +278,7 @@ def untile_flat(flat, shape: tuple, tile_n: int, tile_k: int):
     return x.reshape(lead + (n, k))
 
 
-def encode_blocked_tiled(weights2d: np.ndarray, table: dict,
+def encode_blocked_tiled(weights2d: np.ndarray, table,
                          lut: np.ndarray | None = None,
                          tile_n: int = DEFAULT_TILE_N,
                          tile_k: int = DEFAULT_TILE_K,
@@ -271,13 +286,14 @@ def encode_blocked_tiled(weights2d: np.ndarray, table: dict,
                          seq_len: int = DEFAULT_SEQ_LEN) -> BlockedCompressed:
     """Encode a (N, K) uint8 tensor in the fused-kernel tile-major layout.
 
-    ``block_weights`` is a *cap*: the actual block size is shrunk so a tile
-    always holds a whole number of blocks (see :func:`choose_fused_tiles`).
+    ``block_weights`` is a *cap*: the actual block size is whole tile rows
+    (see :func:`fused_block_weights`), so a tile always holds a whole
+    number of blocks and a block a whole number of rows.
     """
     n, k = weights2d.shape
-    vol = tile_n * tile_k
-    bw = _shrink_block_weights(vol, block_weights, seq_len)
-    assert vol % bw == 0 and bw % seq_len == 0, (tile_n, tile_k, bw, seq_len)
+    bw = fused_block_weights(tile_n, tile_k, block_weights, seq_len)
+    assert bw is not None and (tile_n * tile_k) % bw == 0, (
+        tile_n, tile_k, bw, seq_len)
     stream = tile_stream(np.asarray(weights2d, dtype=np.uint8),
                          tile_n, tile_k)
     bc = encode_blocked(stream, table, lut=lut, block_weights=bw,

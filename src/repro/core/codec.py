@@ -14,13 +14,50 @@ Format (paper Listing 3):
 from __future__ import annotations
 
 import dataclasses
-from collections import Counter
 
 import numpy as np
 
 ESCAPE = 0xFFFF
 DEFAULT_SEQ_LEN = 4
 MAX_TABLE = ESCAPE  # codewords 0..0xFFFE
+
+
+def gram_keys(grams: np.ndarray) -> np.ndarray:
+    """(n, S) uint8 grams → (n,) uint64 keys, first byte most significant,
+    so key order is the rows' lexicographic order."""
+    keys = np.zeros(len(grams), np.uint64)
+    for j in range(grams.shape[1]):
+        keys = (keys << np.uint64(8)) | grams[:, j].astype(np.uint64)
+    return keys
+
+
+class GramIndex:
+    """A {gram → codeword} table as sorted key/code arrays, so a whole
+    tensor's grams look up in one vectorized ``np.searchsorted``.  Build it
+    once per table; every encoder accepts it in place of the dict."""
+
+    def __init__(self, table: dict, sequence_length: int = DEFAULT_SEQ_LEN):
+        self.table = table
+        grams = np.array(list(table.keys()), dtype=np.uint8).reshape(
+            -1, sequence_length)
+        codes = np.fromiter(table.values(), dtype=np.int64, count=len(table))
+        keys = gram_keys(grams)
+        order = np.argsort(keys)
+        self.keys, self.codes = keys[order], codes[order]
+
+    def lookup(self, grams: np.ndarray, missing: int = -1) -> np.ndarray:
+        """(n, S) uint8 grams → (n,) int64 codewords, ``missing`` where
+        the gram is not in the table."""
+        keys = gram_keys(grams)
+        if not len(self.keys):
+            return np.full(len(keys), missing, np.int64)
+        idx = np.minimum(np.searchsorted(self.keys, keys), len(self.keys) - 1)
+        return np.where(self.keys[idx] == keys, self.codes[idx], missing)
+
+
+def as_index(table, sequence_length: int = DEFAULT_SEQ_LEN) -> GramIndex:
+    return (table if isinstance(table, GramIndex)
+            else GramIndex(table, sequence_length))
 
 
 def find_frequent_sequences(weights_list: list[np.ndarray],
@@ -31,11 +68,13 @@ def find_frequent_sequences(weights_list: list[np.ndarray],
     """Paper Listing 2: frequency table over length-``sequence_length``
     subsequences of the flattened quantized weights.
 
-    Returns {tuple(seq) -> codeword}, codewords dense in [0, n_codes).
+    Returns {tuple(seq) -> codeword}, codewords dense in [0, n_codes):
+    by count, descending; ties in first-seen order (arrays in list order,
+    each array's grams in ascending byte order).
     """
-    counter: Counter = Counter()
     budget = sample_cap if sample_cap is not None else float("inf")
-    for w in weights_list:
+    all_keys, all_counts, all_first = [], [], []
+    for i, w in enumerate(weights_list):
         flat = np.ascontiguousarray(w).reshape(-1).astype(np.uint8)
         n = (len(flat) // sequence_length) * sequence_length
         if n == 0:
@@ -44,54 +83,56 @@ def find_frequent_sequences(weights_list: list[np.ndarray],
         if len(grams) > budget:
             grams = grams[: int(budget)]
         budget -= len(grams)
-        # view as void for fast unique
-        u, c = np.unique(grams, axis=0, return_counts=True)
-        for row, cnt in zip(u, c):
-            counter[tuple(int(v) for v in row)] += int(cnt)
+        u, c = np.unique(gram_keys(grams), return_counts=True)
+        all_keys.append(u)
+        all_counts.append(c)
+        all_first.append(np.full(len(u), i))
         if budget <= 0:
             break
-    most = [(seq, cnt) for seq, cnt in counter.most_common(max_codes)
-            if cnt >= min_count]
-    return {seq: i for i, (seq, _) in enumerate(most)}
+    if not all_keys:
+        return {}
+    # keys are unique within an array and arrays are concatenated in
+    # order, so a key's first occurrence names the array it was first seen in
+    u, at, inv = np.unique(np.concatenate(all_keys), return_index=True,
+                           return_inverse=True)
+    counts = np.bincount(inv, weights=np.concatenate(all_counts)).astype(
+        np.int64)
+    first = np.concatenate(all_first)[at]
+    frequent = np.nonzero(counts >= min_count)[0]
+    order = frequent[np.lexsort((u[frequent], first[frequent],
+                                 -counts[frequent]))][:max_codes]
+    shifts = np.arange(sequence_length - 1, -1, -1, dtype=np.uint64) * 8
+    grams = ((u[order][:, None] >> shifts) & np.uint64(0xFF)).astype(int)
+    return {tuple(g): code for code, g in enumerate(grams.tolist())}
 
 
-def compress_array(weights: np.ndarray, table: dict,
+def compress_array(weights: np.ndarray, table,
                    sequence_length: int = DEFAULT_SEQ_LEN) -> np.ndarray:
     """Paper Listing 3, vectorized but format-identical.
 
-    Produces the exact uint16 stream the paper's serial loop produces.
+    Produces the exact uint16 stream the paper's serial loop produces:
+    a hit gram is its codeword, a missed one ESCAPE + its raw values.
     """
     flat = np.ascontiguousarray(weights).reshape(-1).astype(np.uint8)
     n_full = len(flat) // sequence_length
     head = flat[: n_full * sequence_length].reshape(-1, sequence_length)
     tail = flat[n_full * sequence_length:]
 
-    # Vectorized lookup: pack grams to a single uint32 key.
-    if sequence_length == 4:
-        keys = head.astype(np.uint32)
-        packed = (keys[:, 0] << 24) | (keys[:, 1] << 16) | (keys[:, 2] << 8) | keys[:, 3]
-        lut = {}
-        for seq, code in table.items():
-            k = (seq[0] << 24) | (seq[1] << 16) | (seq[2] << 8) | seq[3]
-            lut[k] = code
-        codes = np.array([lut.get(int(k), -1) for k in packed], dtype=np.int64)
-    else:
-        codes = np.array([table.get(tuple(int(v) for v in row), -1)
-                          for row in head], dtype=np.int64)
-
-    out: list[int] = []
+    codes = as_index(table, sequence_length).lookup(head)
     hit = codes >= 0
-    # Serial emission to match the paper's stream exactly (escape layout).
-    for i in range(len(head)):
-        if hit[i]:
-            out.append(int(codes[i]))
-        else:
-            out.append(ESCAPE)
-            out.extend(int(v) for v in head[i])
+    width = np.where(hit, 1, 1 + sequence_length)
+    start = np.concatenate([[0], np.cumsum(width)[:-1]]).astype(np.int64)
+    n_out = int(width.sum()) + (1 + len(tail) if tail.size else 0)
+    out = np.empty(n_out, np.uint16)
+    out[start[hit]] = codes[hit]
+    miss = np.nonzero(~hit)[0]
+    out[start[miss]] = ESCAPE
+    for j in range(sequence_length):
+        out[start[miss] + 1 + j] = head[miss, j]
     if tail.size > 0:
-        out.append(ESCAPE)
-        out.extend(int(v) for v in tail)
-    return np.asarray(out, dtype=np.uint16)
+        out[n_out - 1 - len(tail)] = ESCAPE
+        out[n_out - len(tail):] = tail
+    return out
 
 
 def decompress_array(stream: np.ndarray, table: dict, orig_len: int,

@@ -364,7 +364,7 @@ def planned_tiled_specs(shape: tuple, tiles: int, *, stacked: tuple = (),
     n = out * in_t
     bw = min(block_weights, (n // seq_len) * seq_len) or seq_len
     if tile_n:
-        bw = bcdc._shrink_block_weights(tile_n * tile_k, bw, seq_len)
+        bw = bcdc.fused_block_weights(tile_n, tile_k, bw, seq_len)
         nb = n // bw
     else:
         nb = -(-n // bw)
@@ -500,8 +500,8 @@ def planned_packed_specs(shape: tuple, *, stacked: tuple = (),
     """
     n = int(np.prod(shape))
     if tile_n:
-        bw = bcdc._shrink_block_weights(tile_n * tile_k, block_weights,
-                                        seq_len)
+        bw = bcdc.fused_block_weights(tile_n, tile_k, block_weights,
+                                      seq_len)
         nb = n // bw
     else:
         bw = block_weights

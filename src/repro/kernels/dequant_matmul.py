@@ -50,7 +50,8 @@ def _kernel(x_ref, wq_ref, scale_ref, zero_ref, o_ref, acc_ref, sumx_ref):
         sumx_ref[...] = jnp.zeros_like(sumx_ref)
 
     x = x_ref[...].astype(jnp.bfloat16)                  # (bm, bk)
-    q = wq_ref[...].astype(jnp.bfloat16)                 # (bn, bk) exact ≤255
+    # Mosaic casts uint8 only to 32-bit ints; ≤255 stays exact in bf16.
+    q = wq_ref[...].astype(jnp.int32).astype(jnp.bfloat16)   # (bn, bk)
     acc_ref[...] += jax.lax.dot_general(
         x, q, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)              # (bm, bn)

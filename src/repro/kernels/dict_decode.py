@@ -1,17 +1,26 @@
-"""Blocked dictionary-decode Pallas TPU kernel.
+"""Blocked dictionary-decode Pallas TPU kernel, and the decode core it
+shares with the fused matmul kernels.
 
 The paper decompresses "layer by layer" on CPU; the TPU-native version
 decodes *per VMEM tile* so decompression overlaps the surrounding matmuls
-(DESIGN.md §2).  The decode LUT stays resident in VMEM for every grid step
-(≤ 64k codes × 4 B = 256 KiB), codes/literals stream through per block-chunk.
+(DESIGN.md §2).  The decode LUT stays resident in VMEM for every grid step,
+codes/literals stream through per block-chunk.
 
-One grid step decodes ``chunk`` blocks: a LUT row-gather for dictionary
-slots, plus a rank-gather (in-block cumsum over escape flags) for literal
-slots — both fully vectorized; no serial stream walk remains.
+Mosaic lowers no gather from a table larger than one vreg row, so the
+decode works on 32-bit *words* — one len-4 gram packed little-endian into
+an int32 — and builds every lookup from in-vreg lane gathers
+(``take_along_axis`` over 128 lanes):
 
-Mosaic note: the row-gathers lower to ``dynamic_gather`` on the sublane
-axis; on very old toolchains without gather support ``ops.py`` falls back to
-the jnp oracle (same math, XLA gathers).
+  * dictionary slots: the LUT is resident as an (R, 128) word array.  The
+    kernel sweeps its R rows; row r broadcasts across the codes' sublanes,
+    a lane gather by ``code & 127`` reads it, and a select keeps it where
+    ``code >> 7 == r``.  R ≤ 512 for a full 64k dictionary (256 KiB).
+  * literal slots: a block's escape grams are packed as ``cap`` words;
+    ``rank = cumsum(is_escape) - 1`` (a triangular MXU matmul per 128-lane
+    chunk — Mosaic has no cumsum) selects the row by the same
+    gather-and-select over the literal chunks.
+
+The decoded words are bit-identical to ``ref.dict_decode``'s bytes.
 """
 from __future__ import annotations
 
@@ -23,21 +32,126 @@ from jax.experimental import pallas as pl
 
 from repro.core.codec import ESCAPE
 
-DEFAULT_CHUNK = 8
+DEFAULT_CHUNK = 16
+LANES = 128
+_LUT_ROWS_PER_STEP = 8
 
+
+# ---------------------------------------------------------------------------
+# Word packing (run by the jitted wrappers, outside the kernels).
+# ---------------------------------------------------------------------------
+
+def to_words(grams: jax.Array) -> jax.Array:
+    """(..., S) uint8 grams → (...) int32 words, byte j at bits 8j.
+    S ≤ 4 (the codec's len-4 grams fill one 32-bit word)."""
+    s = grams.shape[-1]
+    assert s <= 4, grams.shape
+    g = grams.astype(jnp.int32)
+    w = g[..., 0]
+    for j in range(1, s):
+        w = w | (g[..., j] << (8 * j))
+    return w
+
+
+def from_words(words: jax.Array, s: int) -> jax.Array:
+    """Inverse of :func:`to_words`: (...) int32 → (..., S) uint8."""
+    return jnp.stack([(words >> (8 * j)) & 0xFF for j in range(s)],
+                     axis=-1).astype(jnp.uint8)
+
+
+def lut_words(lut: jax.Array) -> jax.Array:
+    """(n_codes, S) uint8 LUT → (R, 128) int32, R a multiple of 8; padded
+    rows decode to 0 and are never selected by a valid code."""
+    w = to_words(lut)
+    per = LANES * _LUT_ROWS_PER_STEP
+    w = jnp.pad(w, (0, (-w.shape[0]) % per))
+    return w.reshape(-1, LANES)
+
+
+def literal_words(literals: jax.Array) -> jax.Array:
+    """(..., cap, S) uint8 literal plane → (..., cap') int32 words, cap'
+    rounded up to whole 128-lane chunks."""
+    w = to_words(literals)
+    pad = [(0, 0)] * (w.ndim - 1) + [(0, (-w.shape[-1]) % LANES)]
+    return jnp.pad(w, pad)
+
+
+# ---------------------------------------------------------------------------
+# In-kernel decode core.
+# ---------------------------------------------------------------------------
+
+def _lane_chunks(a, width):
+    return [a[:, i:i + width] for i in range(0, a.shape[1], width)]
+
+
+def _escape_rank(is_esc, width):
+    """Per-row inclusive cumsum of ``is_esc`` minus 1, as lane chunks.
+
+    A 0/1 row chunk times an upper-triangular ones matrix on the MXU is its
+    prefix count (exact: bf16 0/1 inputs, f32 sums ≤ slots); a running
+    carry adds the counts of earlier chunks."""
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (width, width), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (width, width), 1))
+    tri = tri.astype(jnp.float32).astype(jnp.bfloat16)
+    carry = jnp.zeros((is_esc[0].shape[0], 1), jnp.float32)
+    out = []
+    for e in is_esc:
+        cs = jax.lax.dot_general(
+            e.astype(jnp.float32).astype(jnp.bfloat16), tri,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) + carry
+        out.append(cs.astype(jnp.int32) - 1)
+        carry = cs[:, width - 1:width]
+    return out
+
+
+def decode_words(codes, lit_words, lut_ref):
+    """Decode one group of blocks to int32 words.
+
+    codes: (rows, W) integer codes, one block per row; lit_words:
+    (rows, cap') int32, cap' a multiple of 128; lut_ref: the resident
+    (R, 128) int32 LUT ref.  Returns (rows, W) int32 words."""
+    codes = codes.astype(jnp.int32)
+    rows, slots = codes.shape
+    # 128-lane chunks; a row that is no whole number of them (small
+    # shapes, interpret mode only) is one chunk
+    width = LANES if slots % LANES == 0 else slots
+    code_c = _lane_chunks(codes, width)
+    esc_c = [c == ESCAPE for c in code_c]
+    hi_c = [c >> 7 for c in code_c]
+    lo_c = [c & (LANES - 1) for c in code_c]
+
+    def lut_step(i, acc):
+        blk = lut_ref[pl.ds(i * _LUT_ROWS_PER_STEP, _LUT_ROWS_PER_STEP), :]
+        for t in range(_LUT_ROWS_PER_STEP):
+            r = i * _LUT_ROWS_PER_STEP + t
+            row = jnp.broadcast_to(blk[t:t + 1, :], (rows, LANES))
+            acc = [jnp.where(h == r, jnp.take_along_axis(row, lo, axis=1), a)
+                   for a, h, lo in zip(acc, hi_c, lo_c)]
+        return acc
+
+    n_steps = lut_ref.shape[0] // _LUT_ROWS_PER_STEP
+    from_dict = jax.lax.fori_loop(
+        0, n_steps, lut_step, [jnp.zeros_like(c) for c in code_c])
+
+    rank_c = _escape_rank(esc_c, width)
+    from_lit = [jnp.zeros_like(c) for c in code_c]
+    for q, src in enumerate(_lane_chunks(lit_words, LANES)):
+        from_lit = [jnp.where((rk >> 7) == q,
+                              jnp.take_along_axis(src, rk & (LANES - 1),
+                                                  axis=1), a)
+                    for a, rk in zip(from_lit, rank_c)]
+    words = [jnp.where(e, li, d)
+             for e, li, d in zip(esc_c, from_lit, from_dict)]
+    return words[0] if len(words) == 1 else jnp.concatenate(words, axis=1)
+
+
+# ---------------------------------------------------------------------------
+# Standalone decode kernel (the two-step path's first step).
+# ---------------------------------------------------------------------------
 
 def _kernel(codes_ref, lit_ref, lut_ref, o_ref):
-    codes = codes_ref[...].astype(jnp.int32)            # (cb, slots)
-    is_esc = codes == ESCAPE
-    safe = jnp.where(is_esc, 0, codes)
-    from_dict = jnp.take(lut_ref[...], safe, axis=0)    # (cb, slots, S)
-    rank = jnp.clip(jnp.cumsum(is_esc.astype(jnp.int32), axis=1) - 1,
-                    0, lit_ref.shape[1] - 1)            # (cb, slots)
-    lit = lit_ref[...]                                  # (cb, cap, S)
-    from_lit = jnp.take_along_axis(
-        lit, rank[:, :, None].astype(jnp.int32), axis=1)  # (cb, slots, S)
-    out = jnp.where(is_esc[:, :, None], from_lit, from_dict)
-    o_ref[...] = out.reshape(o_ref.shape)
+    o_ref[...] = decode_words(codes_ref[...], lit_ref[...], lut_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -51,19 +165,21 @@ def dict_decode(codes: jax.Array, literals: jax.Array, nlit: jax.Array,
     slots are non-escape).
     """
     nb, slots = codes.shape
-    cap, s = literals.shape[1], literals.shape[2]
+    s = literals.shape[2]
     chunk = min(chunk, nb)
     assert nb % chunk == 0, (nb, chunk)
-    grid = (nb // chunk,)
-    return pl.pallas_call(
+    lits = literal_words(literals)
+    lutw = lut_words(lut)
+    words = pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(nb // chunk,),
         in_specs=[
             pl.BlockSpec((chunk, slots), lambda b: (b, 0)),
-            pl.BlockSpec((chunk, cap, s), lambda b: (b, 0, 0)),
-            pl.BlockSpec(lut.shape, lambda b: (0, 0)),   # LUT resident
+            pl.BlockSpec((chunk, lits.shape[1]), lambda b: (b, 0)),
+            pl.BlockSpec(lutw.shape, lambda b: (0, 0)),   # LUT resident
         ],
-        out_specs=pl.BlockSpec((chunk, slots * s), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, slots * s), jnp.uint8),
+        out_specs=pl.BlockSpec((chunk, slots), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, slots), jnp.int32),
         interpret=interpret,
-    )(codes, literals, lut)
+    )(codes, lits, lutw)
+    return from_words(words, s).reshape(nb, slots * s)

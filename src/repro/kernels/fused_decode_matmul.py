@@ -13,11 +13,10 @@ as QMoE fuses its Huffman-style decode into the GPU GEMM:
   plain PackedLinear).  Each grid step
     1. streams the ``bpt = tile_n·tile_k / block_weights`` compressed
        blocks covering the current (tile_n, tile_k) weight tile into VMEM
-       (codes + literals; the decode LUT is resident in VMEM for the whole
-       launch, ≤ 64k codes × S bytes),
-    2. decodes them in-register — LUT row-gather for dictionary slots, an
-       in-block escape-rank gather for literal slots, identical math to
-       ``dict_decode._kernel``,
+       (codes + literal words; the decode LUT is resident in VMEM for the
+       whole launch as (R, 128) int32 words, ≤ 256 KiB),
+    2. decodes them in-register with ``dict_decode.decode_words`` — the
+       same lane-gather decode as the standalone decode kernel,
     3. feeds the decoded uint8 tile straight into the bf16 MXU matmul with
        the affine epilogue of ``dequant_matmul._kernel``:
 
@@ -30,6 +29,10 @@ planes + one VMEM tile.  This relies on the tile-major block layout of
 (N/tile_n, K/tile_k) grid owns the contiguous block rows
 [t·bpt, (t+1)·bpt), t = j·n_kt + k — so the BlockSpec index maps below can
 address a tile's blocks as one rectangular slab.
+
+Decoded words arrive with rows and columns permuted within each tile (see
+:func:`_decode_tile`); the jitted wrappers permute x's columns, the affine
+rows and the output columns to match, so callers see the plain semantics.
 
 ``grouped_fused_decode_matmul`` is the MoE variant: the grid grows a
 leading expert (plane) axis so one launch sweeps a whole stacked expert
@@ -51,28 +54,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.codec import ESCAPE
+from .dict_decode import decode_words, literal_words, lut_words
 
 DEFAULT_BM = 128
 
 
-def _decode_tile(codes_ref, lit_ref, lut_ref, tn, tk):
-    """Decode one (tile_n, tile_k) weight tile from its compressed blocks —
-    the shared core of both kernels (LUT row-gather for dictionary slots,
-    in-block escape-rank gather for literal slots; identical math to
-    ``dict_decode._kernel``).  The uint8 result lives only in VMEM."""
-    codes = codes_ref[...].astype(jnp.int32)              # (1, bpt, slots)
-    codes = codes.reshape(codes.shape[-2:])               # (bpt, slots)
-    lits = lit_ref[...].reshape(lit_ref.shape[-3:])       # (bpt, cap, S)
-    is_esc = codes == ESCAPE
-    safe = jnp.where(is_esc, 0, codes)
-    from_dict = jnp.take(lut_ref[...], safe, axis=0)      # (bpt, slots, S)
-    rank = jnp.clip(jnp.cumsum(is_esc.astype(jnp.int32), axis=1) - 1,
-                    0, lits.shape[1] - 1)                 # (bpt, slots)
-    from_lit = jnp.take_along_axis(
-        lits, rank[:, :, None].astype(jnp.int32), axis=1)
-    tile = jnp.where(is_esc[:, :, None], from_lit, from_dict)
-    return tile.reshape(tn, tk)                           # uint8, never HBM
+def _decode_tile(codes_ref, lit_ref, lut_ref, tn, tk, s):
+    """Decode one (tile_n, tile_k) weight tile from its compressed blocks.
+    The uint8 values live only in VMEM.
+
+    The tile comes back *permuted*, in the order the word layout gives for
+    free: row ``g·bpt + b`` holds weight row ``b·rpb + g`` (block b spans
+    ``rpb`` rows of the tile) and column ``j·L + c`` holds weight column
+    ``c·S + j`` (byte j of word c; L = tile_k / S words per row).  The
+    wrappers permute x, the affine rows and the output to match, so no
+    in-kernel byte interleave or cross-sublane reshape is needed."""
+    codes = codes_ref[...].reshape(codes_ref.shape[-2:])  # (bpt, W)
+    lits = lit_ref[...].reshape(lit_ref.shape[-2:])       # (bpt, cap')
+    words = decode_words(codes, lits, lut_ref)            # (bpt, W) int32
+    row_words = tk // s
+    rows = []
+    for g in range(words.shape[1] // row_words):
+        wg = words[:, g * row_words:(g + 1) * row_words]
+        planes = [(wg >> (8 * j)) & 0xFF for j in range(s)]
+        rows.append(planes[0] if s == 1 else
+                    jnp.concatenate(planes, axis=1))      # (bpt, tk)
+    q = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+    assert q.shape == (tn, tk), (q.shape, tn, tk)
+    return q                                              # int32 ≤ 255
 
 
 def _accumulate(x, q, acc_ref, sumx_ref):
@@ -85,8 +94,41 @@ def _accumulate(x, q, acc_ref, sumx_ref):
     sumx_ref[...] += jnp.sum(xb.astype(jnp.float32), axis=1, keepdims=True)
 
 
+def _rows_per_block(codes, tile_n, tile_k, s):
+    """How many weight rows of a tile one compressed block spans."""
+    slots = codes.shape[-1]
+    row_words = tile_k // s
+    assert tile_k % s == 0 and slots % row_words == 0, (
+        "fused layout needs whole weight rows per block",
+        codes.shape, tile_n, tile_k, s)
+    rpb = slots // row_words
+    assert tile_n % rpb == 0, (tile_n, rpb)
+    return rpb
+
+
+def _permute_x(x, tile_k, s):
+    """(..., K) columns → per-tile (byte, word) order (see _decode_tile)."""
+    lead, k = x.shape[:-1], x.shape[-1]
+    x = x.reshape(lead + (k // tile_k, tile_k // s, s))
+    return jnp.swapaxes(x, -1, -2).reshape(lead + (k,))
+
+
+def _permute_rows(v, tile_n, rpb):
+    """(..., N, 1) affine rows → per-tile (g, b) order (see _decode_tile)."""
+    lead, n = v.shape[:-2], v.shape[-2]
+    v = v.reshape(lead + (n // tile_n, tile_n // rpb, rpb))
+    return jnp.swapaxes(v, -1, -2).reshape(lead + (n, 1))
+
+
+def _unpermute_cols(y, tile_n, rpb):
+    """Inverse of :func:`_permute_rows` on the output's last axis."""
+    lead, n = y.shape[:-1], y.shape[-1]
+    y = y.reshape(lead + (n // tile_n, rpb, tile_n // rpb))
+    return jnp.swapaxes(y, -1, -2).reshape(lead + (n,))
+
+
 def _kernel(x_ref, codes_ref, lit_ref, lut_ref, scale_ref, zero_ref, o_ref,
-            acc_ref, sumx_ref):
+            acc_ref, sumx_ref, *, s):
     g_idx = pl.program_id(2)
     k_idx = pl.program_id(3)
     ng = pl.num_programs(2)
@@ -98,14 +140,14 @@ def _kernel(x_ref, codes_ref, lit_ref, lut_ref, scale_ref, zero_ref, o_ref,
         sumx_ref[...] = jnp.zeros_like(sumx_ref)
 
     tn, tk = scale_ref.shape[0], x_ref.shape[1]
-    q = _decode_tile(codes_ref, lit_ref, lut_ref, tn, tk)
+    q = _decode_tile(codes_ref, lit_ref, lut_ref, tn, tk, s)
     _accumulate(x_ref[...], q, acc_ref, sumx_ref)
 
     @pl.when((g_idx == ng - 1) & (k_idx == nk - 1))
     def _epilogue():
-        s = scale_ref[...].reshape(1, -1)                 # (1, tn)
+        sc = scale_ref[...].reshape(1, -1)                # (1, tn)
         z = zero_ref[...].reshape(1, -1)                  # (1, tn)
-        o_ref[...] = (s * (acc_ref[...] - sumx_ref[...] * z)
+        o_ref[...] = (sc * (acc_ref[...] - sumx_ref[...] * z)
                       ).astype(o_ref.dtype)
 
 
@@ -120,8 +162,8 @@ def fused_decode_matmul(x: jax.Array, codes: jax.Array, literals: jax.Array,
 
     x: (M, K) float, M % bm == 0; codes/literals: tile-major planes for the
     dense ``shape = (N, K)`` weight; scale/zero: (N, 1) f32.  ``nlit`` is
-    not needed (the escape-rank clip makes over-reads harmless, as in
-    ``dict_decode``).
+    not needed (the escape-rank gather never selects past a block's
+    escapes, as in ``dict_decode``).
 
     Column groups (the shard-local 2D-TP case): codes may carry a leading
     group axis — ``codes (G, nb, slots)``, ``literals (G, nb, cap, S)`` —
@@ -148,22 +190,26 @@ def fused_decode_matmul(x: jax.Array, codes: jax.Array, literals: jax.Array,
     assert m % bm == 0, (m, bm)
     nnt, nkt = n // tile_n, kg // tile_k
     _, nb, slots = codes.shape
-    cap, s = literals.shape[2], literals.shape[3]
+    s = literals.shape[3]
     bpt = nb // (nnt * nkt)
     assert bpt * nnt * nkt == nb and bpt * slots * s == tile_n * tile_k, (
         codes.shape, literals.shape, shape, tile_n, tile_k)
+    rpb = _rows_per_block(codes, tile_n, tile_k, s)
+    lits = literal_words(literals)
+    lutw = lut_words(lut)
+    capw = lits.shape[2]
 
     grid = (m // bm, nnt, groups, nkt)
-    return pl.pallas_call(
-        _kernel,
+    y = pl.pallas_call(
+        functools.partial(_kernel, s=s),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, tile_k), lambda i, j, g, k: (i, g * nkt + k)),
             pl.BlockSpec((1, bpt, slots),
                          lambda i, j, g, k: (g, j * nkt + k, 0)),
-            pl.BlockSpec((1, bpt, cap, s),
-                         lambda i, j, g, k: (g, j * nkt + k, 0, 0)),
-            pl.BlockSpec(lut.shape, lambda i, j, g, k: (0, 0)),  # resident
+            pl.BlockSpec((1, bpt, capw),
+                         lambda i, j, g, k: (g, j * nkt + k, 0)),
+            pl.BlockSpec(lutw.shape, lambda i, j, g, k: (0, 0)),  # resident
             pl.BlockSpec((tile_n, 1), lambda i, j, g, k: (j, 0)),
             pl.BlockSpec((tile_n, 1), lambda i, j, g, k: (j, 0)),
         ],
@@ -172,11 +218,13 @@ def fused_decode_matmul(x: jax.Array, codes: jax.Array, literals: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, tile_n), jnp.float32),
                         pltpu.VMEM((bm, 1), jnp.float32)],
         interpret=interpret,
-    )(x, codes, literals, lut, scale, zero)
+    )(_permute_x(x, tile_k, s), codes, lits, lutw,
+      _permute_rows(scale, tile_n, rpb), _permute_rows(zero, tile_n, rpb))
+    return _unpermute_cols(y, tile_n, rpb)
 
 
 def _grouped_kernel(x_ref, codes_ref, lit_ref, lut_ref, scale_ref, zero_ref,
-                    o_ref, acc_ref, sumx_ref):
+                    o_ref, acc_ref, sumx_ref, *, s):
     k_idx = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -186,14 +234,14 @@ def _grouped_kernel(x_ref, codes_ref, lit_ref, lut_ref, scale_ref, zero_ref,
         sumx_ref[...] = jnp.zeros_like(sumx_ref)
 
     tn, tk = scale_ref.shape[1], x_ref.shape[2]
-    q = _decode_tile(codes_ref, lit_ref, lut_ref, tn, tk)
+    q = _decode_tile(codes_ref, lit_ref, lut_ref, tn, tk, s)
     _accumulate(x_ref[...].reshape(x_ref.shape[-2:]), q, acc_ref, sumx_ref)
 
     @pl.when(k_idx == nk - 1)
     def _epilogue():
-        s = scale_ref[...].reshape(1, -1)                 # (1, tn)
+        sc = scale_ref[...].reshape(1, -1)                # (1, tn)
         z = zero_ref[...].reshape(1, -1)                  # (1, tn)
-        o_ref[...] = (s * (acc_ref[...] - sumx_ref[...] * z)
+        o_ref[...] = (sc * (acc_ref[...] - sumx_ref[...] * z)
                       ).astype(o_ref.dtype).reshape(o_ref.shape)
 
 
@@ -230,22 +278,26 @@ def grouped_fused_decode_matmul(x: jax.Array, codes: jax.Array,
     assert m % bm == 0, (m, bm)
     nnt, nkt = n // tile_n, kdim // tile_k
     _, nb, slots = codes.shape
-    cap, s = literals.shape[2], literals.shape[3]
+    s = literals.shape[3]
     bpt = nb // (nnt * nkt)
     assert bpt * nnt * nkt == nb and bpt * slots * s == tile_n * tile_k, (
         codes.shape, literals.shape, shape, tile_n, tile_k)
+    rpb = _rows_per_block(codes, tile_n, tile_k, s)
+    lits = literal_words(literals)
+    lutw = lut_words(lut)
+    capw = lits.shape[2]
 
     grid = (e, m // bm, nnt, nkt)
-    return pl.pallas_call(
-        _grouped_kernel,
+    y = pl.pallas_call(
+        functools.partial(_grouped_kernel, s=s),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bm, tile_k), lambda ei, i, j, k: (ei, i, k)),
             pl.BlockSpec((1, bpt, slots),
                          lambda ei, i, j, k: (ei, j * nkt + k, 0)),
-            pl.BlockSpec((1, bpt, cap, s),
-                         lambda ei, i, j, k: (ei, j * nkt + k, 0, 0)),
-            pl.BlockSpec(lut.shape, lambda ei, i, j, k: (0, 0)),  # resident
+            pl.BlockSpec((1, bpt, capw),
+                         lambda ei, i, j, k: (ei, j * nkt + k, 0)),
+            pl.BlockSpec(lutw.shape, lambda ei, i, j, k: (0, 0)),  # resident
             pl.BlockSpec((1, tile_n, 1), lambda ei, i, j, k: (ei, j, 0)),
             pl.BlockSpec((1, tile_n, 1), lambda ei, i, j, k: (ei, j, 0)),
         ],
@@ -255,4 +307,6 @@ def grouped_fused_decode_matmul(x: jax.Array, codes: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, tile_n), jnp.float32),
                         pltpu.VMEM((bm, 1), jnp.float32)],
         interpret=interpret,
-    )(x, codes, literals, lut, scale, zero)
+    )(_permute_x(x, tile_k, s), codes, lits, lutw,
+      _permute_rows(scale, tile_n, rpb), _permute_rows(zero, tile_n, rpb))
+    return _unpermute_cols(y, tile_n, rpb)

@@ -109,20 +109,25 @@ DISPATCH_COUNTS = collections.Counter()
 FUSED_SHARD_MAP_MAX_M = 512
 
 
+# Trace-time probe of how each kernel-backed op ran: 'pallas' (compiled
+# Mosaic kernel), 'interpret' (kernel body in the Pallas interpreter) or
+# 'ref' (jnp oracle).  A chip run must show 'pallas' only.
+KERNEL_COUNTS = collections.Counter()
+
+
 def _use_pallas(impl: Impl) -> tuple[bool, bool]:
-    """-> (use_kernel, interpret)"""
+    """-> (use_kernel, interpret).  On a TPU the kernel is always compiled:
+    interpret mode (e.g. from REPRO_TEST_IMPL) never runs on the chip."""
     if impl == "auto":
         impl = _DEFAULT_IMPL
-    if impl == "ref":
+    on_tpu = jax.default_backend() == "tpu"
+    if impl == "ref" or (impl not in ("pallas", "pallas_interpret")
+                         and not on_tpu):
+        KERNEL_COUNTS["ref"] += 1
         return False, False
-    if impl == "pallas":
-        return True, False
-    if impl == "pallas_interpret":
-        return True, True
-    # auto
-    if jax.default_backend() == "tpu":
-        return True, False
-    return False, False
+    interpret = impl == "pallas_interpret" and not on_tpu
+    KERNEL_COUNTS["interpret" if interpret else "pallas"] += 1
+    return True, interpret
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0):
@@ -140,15 +145,43 @@ def dequant_matmul(x, wq, scale, zero, *, out_dtype=jnp.float32,
     """y = x @ dequant(wq).T with per-channel affine (scale, zero).
 
     x: (..., K) float; wq: (N, K) uint8; scale/zero: (N, 1).
-    Leading dims of x are flattened to M.
+    Leading dims of x are flattened to M.  Under a multi-device mesh the
+    kernel runs per device in a shard_map: weight rows (output features)
+    on ``model`` where they divide, x rows on the data axes, so y comes
+    back column-sharded — the layout of the fused compressed path.
     """
+    axis_sizes, mesh, ndev = _mesh_state()
+    if ndev > 1 and not _is_concrete_mesh(mesh):
+        impl = Impl.REF
     use_kernel, interpret = _use_pallas(impl)
     lead = x.shape[:-1]
     kdim = x.shape[-1]
     x2 = x.reshape(-1, kdim)
+    n = wq.shape[0]
     if not use_kernel:
         y = ref.dequant_matmul(x2, wq, scale, zero, out_dtype)
-        return y.reshape(*lead, wq.shape[0])
+        return y.reshape(*lead, n)
+    local = functools.partial(_dequant_matmul_kernel, out_dtype=out_dtype,
+                              interpret=interpret, bm=bm, bn=bn, bk=bk)
+    if ndev <= 1:
+        return local(x2, wq, scale, zero).reshape(*lead, n)
+    from jax.sharding import PartitionSpec as P
+    msize = axis_sizes.get("model", 1)
+    wspec = "model" if msize > 1 and n % msize == 0 else None
+    dsize = axis_sizes.get("data", 1)
+    drow = "data" if dsize > 1 and x2.shape[0] % dsize == 0 else None
+    y = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(drow, None), P(wspec, None), P(wspec, None),
+                  P(wspec, None)),
+        out_specs=P(drow, wspec), check_vma=False)(x2, wq, scale, zero)
+    return y.reshape(*lead, n)
+
+
+def _dequant_matmul_kernel(x2, wq, scale, zero, *, out_dtype, interpret,
+                           bm=None, bn=None, bk=None):
+    """The Pallas kernel on one device, padded to its tile multiples."""
+    kdim = x2.shape[-1]
     kw = {}
     if bm: kw["bm"] = bm
     if bn: kw["bn"] = bn
@@ -164,7 +197,7 @@ def dequant_matmul(x, wq, scale, zero, *, out_dtype=jnp.float32,
     zp, _ = _pad_to(zero, 0, min(bn_, zero.shape[0]))
     y = _dqmm.dequant_matmul(x2, wqp, sp, zp, out_dtype=out_dtype,
                              interpret=interpret, **kw)
-    return y[:m0, :n0].reshape(*lead, n0)
+    return y[:m0, :n0]
 
 
 def dict_decode(codes, literals, nlit, lut, *, impl: Impl = "auto",
@@ -189,7 +222,16 @@ def dict_decode(codes, literals, nlit, lut, *, impl: Impl = "auto",
 
 def flash_attention(q, k, v, *, causal=True, sm_scale=None, q_offset=0,
                     impl: Impl = "auto", bq=None, bk=None, kv_chunk=None):
-    """(B, Hq, Tq, D) × (B, Hkv, Tk, D) → (B, Hq, Tq, D)."""
+    """(B, Hq, Tq, D) × (B, Hkv, Tk, D) → (B, Hq, Tq, D).
+
+    Mosaic kernels are not partitioned automatically, so under a
+    multi-device mesh the kernel runs per device inside a shard_map: batch
+    on the data axes and heads on ``model`` where they divide, replicated
+    otherwise.  An abstract mesh (no devices to map) takes the jnp path.
+    """
+    axis_sizes, mesh, ndev = _mesh_state()
+    if ndev > 1 and not _is_concrete_mesh(mesh):
+        impl = Impl.REF
     use_kernel, interpret = _use_pallas(impl)
     if not use_kernel:
         kw = {"kv_chunk": kv_chunk} if kv_chunk else {}
@@ -198,8 +240,24 @@ def flash_attention(q, k, v, *, causal=True, sm_scale=None, q_offset=0,
     kw = {}
     if bq: kw["bq"] = bq
     if bk: kw["bk"] = bk
-    return _fa.flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               q_offset=q_offset, interpret=interpret, **kw)
+    fn = functools.partial(_fa.flash_attention, causal=causal,
+                           sm_scale=sm_scale, q_offset=q_offset,
+                           interpret=interpret, **kw)
+    if ndev <= 1:
+        return fn(q, k, v)
+    from jax.sharding import PartitionSpec as P
+    batch = tuple(a for a in ("pod", "data") if axis_sizes.get(a, 1) > 1)
+    bsize = 1
+    for a in batch:
+        bsize *= axis_sizes[a]
+    bspec = ((batch if len(batch) > 1 else batch[0])
+             if batch and q.shape[0] % bsize == 0 else None)
+    msize = axis_sizes.get("model", 1)
+    hspec = ("model" if msize > 1 and q.shape[1] % msize == 0
+             and k.shape[1] % msize == 0 else None)
+    spec = P(bspec, hspec, None, None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _mesh_state():
@@ -347,7 +405,6 @@ def _fused_decode_matmul_sharded(x, packed, lut, *, out_dtype, impl: Impl,
     (same dense y); only the output layout differs, and the caller's next
     constraint reshards activation bytes, never weight bytes.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n, kdim = packed.shape
@@ -369,12 +426,12 @@ def _fused_decode_matmul_sharded(x, packed, lut, *, out_dtype, impl: Impl,
                                   tile_k=tile_k, out_dtype=out_dtype,
                                   impl=impl)
 
-    y = shard_map(
+    y = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(drow, None), P(wspec, None), P(wspec, None, None),
                   P(wspec), P(None, None), P(wspec, None), P(wspec, None)),
         out_specs=P(drow, wspec),
-        check_rep=False,
+        check_vma=False,
     )(x2, packed.codes, packed.literals, packed.nlit, lut,
       packed.scale, packed.zero)
     return y.reshape(*lead, n)
@@ -484,7 +541,6 @@ def _tiled_fused_sharded(x, packed, lut, *, out_dtype, impl: Impl,
     and the contraction's partial sums psum over data (the row-parallel
     epilogue), leaving y column-sharded on model.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n, kdim = packed.shape
@@ -510,13 +566,13 @@ def _tiled_fused_sharded(x, packed, lut, *, out_dtype, impl: Impl,
             y = jax.lax.psum(y, daxis)    # row-parallel epilogue reduce
         return y.astype(out_dtype)
 
-    y = shard_map(
+    y = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(prow, daxis), P(daxis, maxis, None),
                   P(daxis, maxis, None, None), P(daxis, maxis),
                   P(None, None), P(maxis, None), P(maxis, None)),
         out_specs=P(prow, maxis),
-        check_rep=False,
+        check_vma=False,
     )(x2, packed.codes, packed.literals, packed.nlit, lut,
       packed.scale, packed.zero)
     return y.reshape(*lead, n)
@@ -618,7 +674,6 @@ def _grouped_fused_sharded(xe, packed, lut, *, out_dtype, impl: Impl, mesh):
     """
     import dataclasses
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local_fn(xl, codes, lits, nlit, lutl, scale, zero):
@@ -628,12 +683,12 @@ def _grouped_fused_sharded(xe, packed, lut, *, out_dtype, impl: Impl, mesh):
                                    impl=impl)
 
     espec = P("model", None, None)
-    y = shard_map(
+    y = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(espec, espec, P("model", None, None, None),
                   P("model", None), P(None, None), espec, espec),
         out_specs=espec,
-        check_rep=False,
+        check_vma=False,
     )(xe, packed.codes, packed.literals, packed.nlit, lut,
       packed.scale, packed.zero)
     return y

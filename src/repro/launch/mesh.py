@@ -8,6 +8,7 @@ the default single device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 AXIS_POD = "pod"
 AXIS_DATA = "data"
@@ -19,17 +20,22 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = (AXIS_POD, AXIS_DATA, AXIS_MODEL) if multi_pod else (AXIS_DATA,
                                                                 AXIS_MODEL)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: tuple, axes: tuple):
-    """Arbitrary mesh (elastic restart targets, tests)."""
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: tuple, axes: tuple, devices=None):
+    """Arbitrary mesh (serving, elastic restart targets, tests).
+
+    Axes are ``Auto``: sharding follows ``partition.constrain`` and the
+    rule tables, as every caller here expects (``jax.make_mesh`` defaults
+    to ``Explicit`` axes, under which those constraints raise)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU tests/examples."""
-    return jax.make_mesh((1, 1), (AXIS_DATA, AXIS_MODEL))
+    return make_mesh((1, 1), (AXIS_DATA, AXIS_MODEL))
 
 
 def data_axes(mesh) -> tuple:

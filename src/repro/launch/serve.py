@@ -3,6 +3,16 @@
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
         --mode compressed --batch 8 --slots 3 --stagger 2 --max-new 16
 
+Widths are the config's smoke widths unless ``--full`` asks for the
+published ones; ``--layers N`` keeps the first N layers (leading dense
+layers included).  Random weights from ``--seed`` are quantized and packed
+on the host CPU, so device memory holds only the served artifact.
+``main(argv)`` runs in-process and returns the printed summary as a dict
+(pack / serve / compile seconds, dispatch and fallback counters, the
+ladder rung that served, per-request outputs, peak device bytes).  The
+persistent compile cache is ``$JAX_COMPILATION_CACHE_DIR`` or
+``<checkout>/.jax_cache`` (``launch/compile_cache.py``).
+
 Drives the request-level API: each of ``--batch`` prompts is submitted as
 a ``serve.Request`` with staggered arrivals (``--stagger`` engine steps
 apart), served by the continuous-batching ``serve.Engine`` over a paged
@@ -58,10 +68,13 @@ import dataclasses
 from repro.configs import get_config
 from repro.core import CompressionPolicy
 from repro.kernels import ops
+from repro.launch import compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import lm as LM
 from repro.serve.context import ServeContext
 from repro.serve.engine import build_serve_params
-from repro.serve.resilience import ResiliencePolicy, ResilientEngine
+from repro.serve.resilience import (FALLBACK_COUNTS, ResiliencePolicy,
+                                    ResilientEngine)
 from repro.serve.scheduler import Engine, Request
 from repro.sharding import partition as PT
 from repro.train.data import DataConfig, DataPipeline
@@ -78,12 +91,20 @@ def _parse_mesh(spec: str | None):
     assert need <= ndev, (f"--mesh {spec} needs {need} devices, have {ndev} "
                           f"(set XLA_FLAGS=--xla_force_host_platform_"
                           f"device_count={need} for CPU)")
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
-def main():
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the config's published widths (default: "
+                         "its reduced smoke widths)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth cut: keep the first N layers, leading "
+                         "dense layers included (0 = all)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
     ap.add_argument("--mode", default="compressed",
                     choices=["dense", "quant", "compressed"])
     ap.add_argument("--batch", type=int, default=4,
@@ -151,33 +172,96 @@ def main():
                          "refuses new work (finished='pressure') instead "
                          "of reclaiming further (0 = the computed "
                          "min_viable floor only)")
-    args = ap.parse_args()
+    return ap
 
-    mesh = _parse_mesh(args.mesh)
-    model_shards = mesh.shape["model"] if mesh is not None else 1
 
-    cfg = get_config(args.arch).smoke
-    params = LM.init_lm(jax.random.PRNGKey(0), cfg, jnp.float32)
-    data = DataPipeline(DataConfig(vocab_size=cfg.vocab_size,
-                                   batch=args.batch,
-                                   seq_len=args.prompt_len))
-    if args.mode == "dense":
-        st, sp, lut = None, params, None
-    else:
+def _serving_config(args):
+    """The config at the requested widths, cut to ``--layers``."""
+    entry = get_config(args.arch)
+    cfg = entry.full if args.full else entry.smoke
+    n_all = cfg.n_layers
+    lead = cfg.first_dense_layers if cfg.family == "moe" else 0
+    if args.layers:
+        if not lead < args.layers <= n_all:
+            raise SystemExit(f"--layers {args.layers}: {cfg.name} has "
+                             f"{n_all} layers, {lead} of them leading "
+                             f"dense layers that the cut keeps")
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    print(f"depth: kept {cfg.n_layers} of {n_all} layers "
+          f"({lead} leading dense) at {'published' if args.full else 'smoke'}"
+          f" widths, d_model {cfg.d_model}")
+    return cfg
+
+
+def _build_on_host(args, cfg, model_shards):
+    """Random weights from ``--seed`` and the served artifact, built on
+    the host CPU so the dense f32 parameters never occupy device memory.
+    Returns (serve state or None, served params, lut)."""
+    with jax.default_device(jax.local_devices(backend="cpu")[0]):
+        params = LM.init_lm(jax.random.PRNGKey(args.seed), cfg, jnp.float32)
+        if args.mode == "dense":
+            return None, params, None
         st = build_serve_params(
             params, CompressionPolicy(mode=args.mode, min_weight_size=1024,
                                       tiles=args.tiles),
             model_shards=model_shards)
-        sp, lut = st.params, st.lut
-        print(f"{args.mode} weights: {sum(st.stats.values())/2**20:.2f} MiB")
+    return st, st.params, st.lut
 
+
+def _place(sp, lut, mesh, keep_experts_on_host: bool):
+    """Move the served artifact to the device(s): per the partition rules
+    on a mesh (lut replicated), else onto the default device — except
+    tiered-residency expert stacks, whose backing tier is host RAM."""
     if mesh is not None:
-        # place params per the partition rules; lut replicates
         specs = PT.make_param_specs(sp, mesh, PT.ShardingConfig(mode="serve"))
         sp = jax.device_put(sp, PT.to_named(specs, mesh))
         if lut is not None:
             lut = jax.device_put(
                 lut, jax.NamedSharding(mesh, jax.sharding.PartitionSpec()))
+        return sp, lut
+    dev = jax.devices()[0]
+    experts = None
+    if keep_experts_on_host:
+        experts = sp["blocks"]["moe"]["experts"]
+        sp = {**sp, "blocks": {**sp["blocks"], "moe": {
+            **sp["blocks"]["moe"], "experts": None}}}
+    sp = jax.device_put(sp, dev)
+    if experts is not None:
+        sp["blocks"]["moe"]["experts"] = experts
+    return sp, (None if lut is None else jax.device_put(lut, dev))
+
+
+def main(argv=None) -> dict:
+    """Run the launcher; returns the printed summary as a dict."""
+    args = _parser().parse_args(argv)
+    cache = compile_cache.setup()
+    with compile_cache.CompileClock() as clock:
+        summary = _serve(args)
+    summary.update(compile_s=clock.seconds, compiles=clock.compiles,
+                   cache_dir=cache)
+    print(f"compile: {clock.seconds:.2f} s in {clock.compiles} backend "
+          f"compiles (cache {cache})")
+    return summary
+
+
+def _serve(args) -> dict:
+    mesh = _parse_mesh(args.mesh)
+    model_shards = mesh.shape["model"] if mesh is not None else 1
+    cfg = _serving_config(args)
+    data = DataPipeline(DataConfig(vocab_size=cfg.vocab_size,
+                                   batch=args.batch,
+                                   seq_len=args.prompt_len,
+                                   seed=args.seed))
+    t_pack = time.perf_counter()
+    st, sp, lut = _build_on_host(args, cfg, model_shards)
+    pack_s = time.perf_counter() - t_pack
+    mib = None
+    if st is not None:
+        mib = sum(st.stats.values()) / 2**20
+        print(f"{args.mode} weights: {mib:.2f} MiB (packed on host in "
+              f"{pack_s:.2f} s)")
+    sp, lut = _place(sp, lut, mesh, args.residency == "tiered")
+    if mesh is not None:
         print(f"mesh: {dict(mesh.shape)}")
 
     max_len = args.prompt_len + args.max_new
@@ -281,6 +365,7 @@ def main():
     toks = np.asarray(data.batch_at(0)["tokens"])
     arrivals = [i * args.stagger for i in range(args.batch)]
     ops.DISPATCH_COUNTS.clear()
+    FALLBACK_COUNTS.clear()
 
     t = time.perf_counter()
     submitted = 0
@@ -326,8 +411,32 @@ def main():
               f"rung_latency_s {s['rung_latency_s']}")
     by_rid = {c.rid: c for c in eng.completions}
     print("sample:", by_rid[0].tokens[args.prompt_len:].tolist())
+    peak = _peak_bytes(mesh)
+    if peak is not None:
+        print(f"device memory: peak_bytes_in_use {peak}")
     eng.close()       # stop the residency prefetch worker (no leaked
     # threads — asserted in tests; see Engine.close)
+    return dict(
+        arch=args.arch, config=cfg.name, n_layers=cfg.n_layers,
+        mode=args.mode, completed=h["completed"], tokens=n_tok,
+        steps=h["steps"], serve_s=dt, pack_s=pack_s, compressed_mib=mib,
+        reasons=reasons, dispatch=dict(ops.DISPATCH_COUNTS),
+        fallbacks=dict(FALLBACK_COUNTS),
+        last_rung=rengine.last_rung if rengine is not None else None,
+        outputs={rid: c.tokens[args.prompt_len:].tolist()
+                 for rid, c in by_rid.items()},
+        peak_bytes_in_use=peak)
+
+
+def _peak_bytes(mesh):
+    """Peak device bytes per device where the backend reports them."""
+    devs = (list(mesh.devices.flat) if mesh is not None
+            else jax.devices()[:1])
+    stats = [d.memory_stats() for d in devs]
+    if not all(stats):
+        return None
+    peaks = [st.get("peak_bytes_in_use") for st in stats]
+    return peaks[0] if len(peaks) == 1 else peaks
 
 
 if __name__ == "__main__":

@@ -65,6 +65,7 @@ def serve_param_specs(cfg, policy: CompressionPolicy,
     column tiles) so the grouped expert megakernel path stays reachable.
     """
     from repro.core.blocked_codec import choose_fused_tiles
+    from repro.serve.engine import tile_shards
 
     dense = dense_param_specs(cfg, dtype)
     flat, treedef = jax.tree_util.tree_flatten_with_path(dense)
@@ -95,9 +96,13 @@ def serve_param_specs(cfg, policy: CompressionPolicy,
             else:
                 from repro.sharding.partition import (clean_keystr,
                                                       is_row_parallel)
-                picked = choose_fused_tiles(shape2, policy.block_weights,
-                                            shards=(model_shards, 1))
-                tn, tk = picked[:2] if picked else (0, 0)
+                picked = choose_fused_tiles(
+                    shape2, policy.block_weights,
+                    shards=tile_shards(name, model_shards))
+                if picked is None:  # as build_serve_params: quant-only
+                    out.append(planned_quant_specs(shape2, stacked=lead))
+                    continue
+                tn, tk = picked[:2]
                 pl = planned_packed_specs(
                     shape2, stacked=lead,
                     block_weights=policy.block_weights,

@@ -727,7 +727,6 @@ def apply_moe_local(p: Params, x: jax.Array, cfg, *, lut=None,
     legacy shape: materialize the dense stack outside, shard it on the
     expert dim.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.sharding.partition import current_mesh
 
@@ -783,11 +782,11 @@ def apply_moe_local(p: Params, x: jax.Array, cfg, *, lut=None,
         # shard_map signature uniform
         lut_in, lspec = jnp.zeros((1, 1), jnp.uint8), P(None, None)
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(xspec, P(None, None), lspec) + wspecs,
         out_specs=(xspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, router_w, lut_in, wg_in, wu_in, wd_in)
 
     if "shared" in p:
@@ -918,9 +917,9 @@ def apply_moe(p: Params, x: jax.Array, cfg, *, lut=None, impl: str = "auto",
         ye = _expert_ffn(p["experts"], xe, lut, impl)      # (e, cap, d)
 
     ye = constrain(ye, "model", None, None)
-    out = jnp.zeros((n_tok + 1, d), x.dtype)
-    out = out.at[table].add(ye * gtable[..., None].astype(x.dtype))
-    y = out[:n_tok]
+    # empty table entries point one past the last token: dropped
+    y = jnp.zeros((n_tok, d), x.dtype).at[table].add(
+        ye * gtable[..., None].astype(x.dtype), mode="drop")
 
     if "shared" in p:
         y = y + apply_mlp(p["shared"], xf, lut=lut, impl=impl)

@@ -53,7 +53,9 @@ import collections
 import contextlib
 import dataclasses
 import functools
+import os
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import Any, Optional
 
@@ -62,12 +64,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (CompressionPolicy, QuantConfig, build_lut,
-                        encode_blocked, find_frequent_sequences,
+                        find_frequent_sequences,
                         quantize_linear)
 from repro.core.compressed import (PackedLinear, QuantLinear,
                                    TiledPackedLinear, encode_tiled_planes,
                                    pad_literals)
 from repro.core import blocked_codec as bcdc
+from repro.core.codec import GramIndex
 from repro.core.blocked_codec import DEFAULT_BLOCK_WEIGHTS
 from repro.models import lm as LM
 from repro.models import encdec as ED
@@ -92,6 +95,27 @@ def _iter_weight_paths(params):
         yield jax.tree_util.keystr(path), leaf
 
 
+def _column_tiled(policy: CompressionPolicy, name: str, shape2) -> bool:
+    """Stored as 2D-TP TiledPackedLinear column tiles?  Expert stacks never
+    are: they stay stacked PackedLinear so the grouped expert megakernel
+    keeps them compressed-resident under expert parallelism."""
+    return (policy.tiles > 1 and shape2[-1] % policy.tiles == 0
+            and "experts" not in name)
+
+
+def _unstack(lead: tuple, *stacks):
+    """(S, ...) stacks over a leaf's S = prod(lead) sub-tensors → ``lead +
+    (...)``; with no lead dims the single sub-tensor's own shape."""
+    return [a.reshape(lead + a.shape[1:]) for a in stacks]
+
+
+def tile_shards(name: str, model_shards: int) -> tuple:
+    """The (out, in) shard counts a weight's fused tiles must divide:
+    out-features split over the model axis, except for expert stacks,
+    whose expert axis is what splits (expert parallelism)."""
+    return (1, 1) if "experts" in name else (model_shards, 1)
+
+
 def build_serve_params(params: Any, policy: CompressionPolicy,
                        *, qcfg: QuantConfig | None = None,
                        table: dict | None = None,
@@ -108,9 +132,9 @@ def build_serve_params(params: Any, policy: CompressionPolicy,
     fused tile choice then divides the per-shard out dim so sharded
     serving dispatches to the shard-mapped fused megakernel instead of
     falling back to the two-step path (see ``ops.decode_dequant_matmul``).
-    The same divisor is applied to stacked expert planes, so the per-model
-    -shard slice of ``moe_d_ff`` stays tile-aligned for the grouped expert
-    megakernel.  ``policy.tiles > 1`` stores eligible weights as
+    Stacked expert planes are not divided: expert parallelism splits the
+    expert axis, so each device runs the grouped expert megakernel over
+    whole expert weights.  ``policy.tiles > 1`` stores eligible weights as
     TiledPackedLinear column tiles (2D-TP resident storage, §Perf D2),
     also tile-major — except expert stacks, which stay stacked
     PackedLinear (grouped-kernel eligible).
@@ -133,6 +157,14 @@ def build_serve_params(params: Any, policy: CompressionPolicy,
             continue
         shape2 = leaf.shape[-2:]         # per-layer dense shape
         act = policy.action(name, shape2)
+        if (act == "compressed" and not _column_tiled(policy, name, shape2)
+                and bcdc.choose_fused_tiles(
+                    shape2, bw, shards=tile_shards(name, model_shards))
+                is None):
+            # No kernel-blockable tile (deepseek's 10944-wide dense FFN):
+            # compressed planes could only serve through the two-step path
+            # that writes the dense bytes to HBM, so store it quant-only.
+            act = "quant"
         actions.append(act)
         if act in ("quant", "compressed"):
             stacked = leaf.reshape((-1,) + shape2)
@@ -146,114 +178,93 @@ def build_serve_params(params: Any, policy: CompressionPolicy,
     # Pass 2: one model-wide dictionary (paper: single table per model).
     if table is None and streams:
         table = find_frequent_sequences(streams, max_codes=65535)
-    lut = None
+    lut = index = None
     if table is not None:
         lut = jnp.asarray(build_lut(table))  # empty table → 1 zero row
+        index = GramIndex(table)
 
-    # Pass 3: build containers.
+    # Pass 3: build containers.  Sub-tensors encode on a thread pool: the
+    # numpy lookups release the GIL.
+    np_lut = None if lut is None else np.asarray(lut)
     new_leaves = []
     n_bytes = {"dense": 0, "quant": 0, "compressed": 0}
-    for i, (path, leaf) in enumerate(flat):
-        act = actions[i]
-        if act == "dense":
-            new_leaves.append(leaf)
-            if hasattr(leaf, "nbytes"):
-                n_bytes["dense"] += int(leaf.nbytes)
-            continue
-        qls = quantized[i]
-        lead = leaf.shape[:-2]
-        if act == "quant":
-            vals = jnp.stack([q.values for q in qls]).reshape(
-                lead + leaf.shape[-2:]).astype(jnp.uint8)
-            sc = jnp.stack([q.scale for q in qls]).reshape(
-                lead + (leaf.shape[-2], 1))
-            zr = jnp.stack([q.zero for q in qls]).reshape(
-                lead + (leaf.shape[-2], 1))
-            new_leaves.append(QuantLinear(vals, sc, zr))
-            n_bytes["quant"] += int(vals.nbytes + sc.nbytes + zr.nbytes)
-        elif (policy.tiles > 1 and leaf.shape[-1] % policy.tiles == 0
-              and "experts" not in jax.tree_util.keystr(path)):
-            # 2D-TP column-tile storage, fused tile-major per tile.
-            # Expert stacks are excluded: they stay stacked PackedLinear so
-            # the grouped expert megakernel keeps them compressed-resident
-            # under expert parallelism (column tiles would strand them on
-            # the dense-materialize path).
-            per = [encode_tiled_planes(
-                np.asarray(q.values, dtype=np.uint8), table,
-                np.asarray(lut), policy.tiles, block_weights=bw,
-                tile="auto", shards=(model_shards, 1)) for q in qls]
-            tn, tk = per[0][1], per[0][2]
-            cap = max(bc.literals.shape[1]
-                      for bcs, _, _ in per for bc in bcs)
+    with ThreadPoolExecutor(max_workers=os.cpu_count() or 1) as encode_pool:
+        for i, (path, leaf) in enumerate(flat):
+            act = actions[i]
+            if act == "dense":
+                new_leaves.append(leaf)
+                if hasattr(leaf, "nbytes"):
+                    n_bytes["dense"] += int(leaf.nbytes)
+                continue
+            qls = quantized[i]
+            lead = leaf.shape[:-2]
+            if act == "quant":
+                vals = jnp.stack([q.values for q in qls]).reshape(
+                    lead + leaf.shape[-2:]).astype(jnp.uint8)
+                sc = jnp.stack([q.scale for q in qls]).reshape(
+                    lead + (leaf.shape[-2], 1))
+                zr = jnp.stack([q.zero for q in qls]).reshape(
+                    lead + (leaf.shape[-2], 1))
+                new_leaves.append(QuantLinear(vals, sc, zr))
+                n_bytes["quant"] += int(vals.nbytes + sc.nbytes + zr.nbytes)
+            elif _column_tiled(policy, jax.tree_util.keystr(path),
+                               leaf.shape[-2:]):
+                # 2D-TP column-tile storage, fused tile-major per tile.
+                per = list(encode_pool.map(lambda q: encode_tiled_planes(
+                    np.asarray(q.values, dtype=np.uint8), index, np_lut,
+                    policy.tiles, block_weights=bw, tile="auto",
+                    shards=(model_shards, 1)), qls))
+                tn, tk = per[0][1], per[0][2]
+                cap = max(bc.literals.shape[1]
+                          for bcs, _, _ in per for bc in bcs)
 
-            def stackplane(f):
-                return jnp.stack([jnp.stack([f(bc) for bc in bcs])
-                                  for bcs, _, _ in per])
+                def stackplane(f):
+                    return jnp.stack([jnp.stack([f(bc) for bc in bcs])
+                                      for bcs, _, _ in per])
 
-            codes = stackplane(lambda bc: bc.codes)
-            lits = stackplane(lambda bc: pad_literals(bc.literals, cap))
-            nlit = stackplane(lambda bc: bc.nlit)
-            sc = jnp.stack([q.scale for q in qls])
-            zr = jnp.stack([q.zero for q in qls])
-            if lead:
-                codes = codes.reshape(lead + codes.shape[1:])
-                lits = lits.reshape(lead + lits.shape[1:])
-                nlit = nlit.reshape(lead + nlit.shape[1:])
-                sc = sc.reshape(lead + sc.shape[1:])
-                zr = zr.reshape(lead + zr.shape[1:])
+                codes = stackplane(lambda bc: bc.codes)
+                lits = stackplane(lambda bc: pad_literals(bc.literals, cap))
+                nlit = stackplane(lambda bc: bc.nlit)
+                sc = jnp.stack([q.scale for q in qls])
+                zr = jnp.stack([q.zero for q in qls])
+                codes, lits, nlit, sc, zr = _unstack(lead, codes, lits, nlit,
+                                                     sc, zr)
+                tl = TiledPackedLinear(codes, lits, nlit, sc, zr,
+                                       shape=tuple(leaf.shape[-2:]),
+                                       tile_n=tn, tile_k=tk)
+                new_leaves.append(tl)
+                n_bytes["compressed"] += tl.payload_nbytes + int(
+                    sc.nbytes + zr.nbytes)
             else:
-                codes, lits, nlit = codes[0], lits[0], nlit[0]
-                sc, zr = sc[0], zr[0]
-            tl = TiledPackedLinear(codes, lits, nlit, sc, zr,
-                                   shape=tuple(leaf.shape[-2:]),
-                                   tile_n=tn, tile_k=tk)
-            new_leaves.append(tl)
-            n_bytes["compressed"] += tl.payload_nbytes + int(
-                sc.nbytes + zr.nbytes)
-        else:
-            # Tile-major layout when the shape admits it, so serving hits
-            # the fused decode→dequant→matmul megakernel; linear layout
-            # (tile 0×0) otherwise → two-step fallback path.  The tile
-            # choice divides the per-``model_shards`` out dim so the
-            # shard-mapped fused path stays reachable on the target mesh.
-            tiles = bcdc.choose_fused_tiles(leaf.shape[-2:], bw,
-                                            shards=(model_shards, 1))
-            tn, tk = tiles[:2] if tiles else (0, 0)
-            # encode each sub-tensor with a uniform literal capacity
-            if tiles:
-                bcs = [bcdc.encode_blocked_tiled(
-                    np.asarray(q.values, dtype=np.uint8), table,
-                    lut=np.asarray(lut), tile_n=tn, tile_k=tk,
-                    block_weights=bw) for q in qls]
-            else:
-                bcs = [encode_blocked(np.asarray(q.values, dtype=np.uint8),
-                                      table, lut=np.asarray(lut),
-                                      block_weights=bw) for q in qls]
-            cap = max(bc.literals.shape[1] for bc in bcs)
-            codes = jnp.stack([bc.codes for bc in bcs])
-            lits = jnp.stack([pad_literals(bc.literals, cap) for bc in bcs])
-            nlit = jnp.stack([bc.nlit for bc in bcs])
-            sc = jnp.stack([q.scale for q in qls])
-            zr = jnp.stack([q.zero for q in qls])
-            if lead:
-                codes = codes.reshape(lead + codes.shape[1:])
-                lits = lits.reshape(lead + lits.shape[1:])
-                nlit = nlit.reshape(lead + nlit.shape[1:])
-                sc = sc.reshape(lead + sc.shape[1:])
-                zr = zr.reshape(lead + zr.shape[1:])
-            else:
-                codes, lits, nlit = codes[0], lits[0], nlit[0]
-                sc, zr = sc[0], zr[0]
-            from repro.sharding.partition import (clean_keystr,
-                                                  is_row_parallel)
-            pl = PackedLinear(codes, lits, nlit, sc, zr,
-                              shape=tuple(leaf.shape[-2:]),
-                              row_parallel=is_row_parallel(
-                                  clean_keystr(jax.tree_util.keystr(path))),
-                              tile_n=tn, tile_k=tk)
-            new_leaves.append(pl)
-            n_bytes["compressed"] += pl.payload_nbytes + int(
-                sc.nbytes + zr.nbytes)
+                # Tile-major layout, so serving hits the fused
+                # decode→dequant→matmul megakernel.  The tile choice divides
+                # the per-``model_shards`` out dim so the shard-mapped fused
+                # path stays reachable on the target mesh.
+                tn, tk, _ = bcdc.choose_fused_tiles(
+                    leaf.shape[-2:], bw,
+                    shards=tile_shards(jax.tree_util.keystr(path), model_shards))
+                # encode each sub-tensor with a uniform literal capacity
+                bcs = list(encode_pool.map(lambda q: bcdc.encode_blocked_tiled(
+                    np.asarray(q.values, dtype=np.uint8), index,
+                    lut=np_lut, tile_n=tn, tile_k=tk, block_weights=bw), qls))
+                cap = max(bc.literals.shape[1] for bc in bcs)
+                codes = jnp.stack([bc.codes for bc in bcs])
+                lits = jnp.stack([pad_literals(bc.literals, cap) for bc in bcs])
+                nlit = jnp.stack([bc.nlit for bc in bcs])
+                sc = jnp.stack([q.scale for q in qls])
+                zr = jnp.stack([q.zero for q in qls])
+                codes, lits, nlit, sc, zr = _unstack(lead, codes, lits, nlit,
+                                                     sc, zr)
+                from repro.sharding.partition import (clean_keystr,
+                                                      is_row_parallel)
+                pl = PackedLinear(codes, lits, nlit, sc, zr,
+                                  shape=tuple(leaf.shape[-2:]),
+                                  row_parallel=is_row_parallel(
+                                      clean_keystr(jax.tree_util.keystr(path))),
+                                  tile_n=tn, tile_k=tk)
+                new_leaves.append(pl)
+                n_bytes["compressed"] += pl.payload_nbytes + int(
+                    sc.nbytes + zr.nbytes)
 
     params_out = treedef.unflatten(new_leaves)
     if lut is not None:

@@ -38,7 +38,8 @@ def _reset_probe_counters():
     from repro.kernels import ops
     from repro.models import layers
     from repro.serve import engine, residency, resilience
-    for counter in (ops.DISPATCH_COUNTS, engine.TRACE_COUNTS,
+    for counter in (ops.DISPATCH_COUNTS, ops.KERNEL_COUNTS,
+                    engine.TRACE_COUNTS,
                     layers.MATERIALIZE_COUNTS, resilience.FALLBACK_COUNTS,
                     residency.RESIDENCY_COUNTS):
         counter.clear()

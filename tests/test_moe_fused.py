@@ -25,6 +25,7 @@ from repro.configs import get_config
 from repro.core.compressed import PackedLinear, pack_expert_stack
 from repro.core.policy import CompressionPolicy
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.models import layers as L
 
 
@@ -218,6 +219,7 @@ from repro.kernels import ops
 from repro.models import layers as L
 from repro.models import lm as LM
 from repro.serve.engine import build_serve_params
+from repro.launch.mesh import make_mesh
 from repro.sharding import partition as PT
 
 cfg = get_config("deepseek-v2-lite-16b").smoke
@@ -251,7 +253,7 @@ def relerr(a, b):
 for shape, want in (((1, 1), "grouped_fused"),
                     ((2, 4), "grouped_fused_shard_map"),
                     ((8, 1), "grouped_unfused")):
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     ops.DISPATCH_COUNTS.clear()
     L.MATERIALIZE_COUNTS.clear()
     lf = prefill_logits(cfg, mesh, "auto")
@@ -269,7 +271,7 @@ for shape, want in (((1, 1), "grouped_fused"),
 # planes enter the shard_map expert-sharded, grouped kernel runs per shard
 cfg_l = dataclasses.replace(cfg, moe_local_dispatch=True,
                             name=cfg.name + "-local")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ops.DISPATCH_COUNTS.clear()
 L.MATERIALIZE_COUNTS.clear()
 lf = prefill_logits(cfg_l, mesh, "auto")
@@ -315,7 +317,7 @@ def test_moe_generate_grouped_shard_map_8dev(rng):
     st = build_serve_params(params, CompressionPolicy(mode="compressed",
                                                       min_weight_size=1024),
                             model_shards=4)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     specs = PT.make_param_specs(st.params, mesh,
                                 PT.ShardingConfig(mode="serve"))
     sp = jax.device_put(st.params, PT.to_named(specs, mesh))

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core import CompressionPolicy
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.models import lm as LM
 from repro.serve import engine as engine_mod
 from repro.serve.context import ServeContext
@@ -558,7 +559,7 @@ def test_scheduler_sharded_parity_8dev():
     st = build_serve_params(
         params, CompressionPolicy(mode="compressed", min_weight_size=1024),
         model_shards=4)                    # tiles divide the model axis
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     specs = PT.make_param_specs(st.params, mesh,
                                 PT.ShardingConfig(mode="serve"))
     sp = jax.device_put(st.params, PT.to_named(specs, mesh))

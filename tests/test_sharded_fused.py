@@ -24,6 +24,7 @@ from repro.core.blocked_codec import build_lut, choose_fused_tiles
 from repro.core.compressed import (pack_linear, pack_linear_tiled,
                                    quantize_linear)
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 
 def _packed(rng, n, k, msize=1, tiles=0):
@@ -87,6 +88,7 @@ from repro.core import codec
 from repro.core.blocked_codec import build_lut, choose_fused_tiles
 from repro.core.compressed import pack_linear, pack_linear_tiled, quantize_linear
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.sharding import partition as PT
 
 rng = np.random.default_rng(0)
@@ -104,7 +106,7 @@ def relerr(a, b):
 
 for mesh_shape in ((1, 1), (2, 4), (8, 1)):
     dsz, msz = mesh_shape
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     single = dsz * msz == 1
     # m=131: prime, > DEFAULT_BM once padded -> exercises the M-tile padding
     for (m, n, k) in ((16, 64, 128), (131, 64, 256)):
@@ -146,7 +148,7 @@ for mesh_shape in ((1, 1), (2, 4), (8, 1)):
 
 # out-tile count that does NOT divide the weight axes -> graceful two-step
 # fallback (probe proves it), numerics still exact
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 w, packed, table, lut_np = build(64, 128, 1)   # tile_n=64 -> nnt=1, 1 % 4 != 0
 lut = jnp.asarray(lut_np)
 assert (64 // packed.tile_n) % 4 != 0
@@ -182,7 +184,7 @@ def test_sharded_fused_inprocess_8dev(rng):
     """Direct (non-subprocess) version for the multi-device CI job: the
     2×4 mesh must take both shard-mapped fused paths and match unfused."""
     from repro.sharding import partition as PT
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     p, lut = _packed(rng, 64, 256, msize=4)
     pt, lutt = _packed(rng, 64, 256, msize=4, tiles=8)
     x = jnp.asarray(rng.normal(size=(16, 256)).astype(np.float32))

@@ -49,9 +49,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.sharding import partition as PT
 
-mesh = jax.make_mesh((2, 8), ("data", "model"))
+mesh = make_mesh((2, 8), ("data", "model"))
 
 # --- dense rules ---
 params = {
@@ -145,6 +146,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import layers as L
 from repro.sharding import partition as PT
 
@@ -154,7 +156,7 @@ p = L.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model)) * 0.5
 y_g, aux_g = L.apply_moe(p, x, cfg)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg_l = dataclasses.replace(cfg, moe_local_dispatch=True)
 with mesh, PT.active_mesh(mesh):
     y_l, aux_l = jax.jit(lambda p_, x_: L.apply_moe(p_, x_, cfg_l))(p, x)
