@@ -44,6 +44,12 @@ the guard's ``kind`` is 'replay' for those probes.  Overload events the
 scheduler accounts for (shed / expired / preempt) tick the same counter,
 so ``health()['fallbacks']`` is the one place CI asserts the whole
 robustness matrix.
+
+Each rung attempt runs inside a ``guard.call`` profiler span (arguments
+``kind``, ``rung``, ``attempt``) holding ``guard.dispatch`` (the call:
+trace on a cache miss, enqueue), ``guard.wait`` (``block_until_ready``)
+and ``guard.effects`` (the effects barrier), so a profile tells a program
+that started late from one whose end reached the host late.
 """
 from __future__ import annotations
 
@@ -53,6 +59,7 @@ import time
 from typing import Any, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.integrity import (IntegrityError, check_invariants,
                                   verify_serve_state)
@@ -148,7 +155,9 @@ class ResilientEngine:
         self.invariant_report = None
         self.requests = 0
         self.last_rung: Optional[str] = None
-        self._history: list = []          # [(rung, attempt, repr(exc))]
+        # [(rung, attempt, repr(exc))]: the newest, as many as health()
+        # reports
+        self._history = collections.deque(maxlen=8)
         if self.policy.verify != "off":
             self._integrity_gate()
 
@@ -192,27 +201,32 @@ class ResilientEngine:
             _dispatch.runtime_tokens.clear()
             raise
 
-    def _run_rung(self, rung: str, fn, *args, **kw):
+    def _run_rung(self, rung: str, fn, kind: str, attempt: int):
         lever = _RUNG_IMPL.get(rung)
         prev = ops._DEFAULT_IMPL
-        try:
-            if lever is not None:
-                ops.set_default_impl(lever)
-            out = fn(*args, **kw)
-            jax.block_until_ready(out)    # surface faults inside the rung
-            self._effects_barrier()
-            return out
-        except jax.errors.JaxRuntimeError:
-            # The fault may be parked on BOTH the value outputs and the
-            # ordered-effects token; drain the token here so a stale
-            # poisoned one can't fail the next (healthy) rung.
+        with TraceAnnotation("guard.call", kind=kind, rung=rung,
+                             attempt=attempt):
             try:
-                self._effects_barrier()
+                if lever is not None:
+                    ops.set_default_impl(lever)
+                with TraceAnnotation("guard.dispatch"):
+                    out = fn()
+                with TraceAnnotation("guard.wait"):
+                    jax.block_until_ready(out)    # surface faults here
+                with TraceAnnotation("guard.effects"):
+                    self._effects_barrier()
+                return out
             except jax.errors.JaxRuntimeError:
-                pass
-            raise
-        finally:
-            ops.set_default_impl(prev)
+                # The fault may be parked on BOTH the value outputs and
+                # the ordered-effects token; drain the token here so a
+                # stale poisoned one can't fail the next (healthy) rung.
+                try:
+                    self._effects_barrier()
+                except jax.errors.JaxRuntimeError:
+                    pass
+                raise
+            finally:
+                ops.set_default_impl(prev)
 
     def _deadline_check(self, t0: float, deadline: float):
         if deadline and time.monotonic() - t0 > deadline:
@@ -220,12 +234,15 @@ class ResilientEngine:
             raise DeadlineExceeded(
                 f"request exceeded {deadline:.3f}s "
                 f"(elapsed {time.monotonic() - t0:.3f}s; "
-                f"history {self._history[-4:]})")
+                f"history {list(self._history)[-4:]})")
 
-    def _with_ladder(self, make_call, *, deadline_s: Optional[float]):
-        """Retry/ladder walk shared by generate and prefill.
+    def _with_ladder(self, make_call, kind: str, *,
+                     deadline_s: Optional[float]):
+        """Retry/ladder walk shared by generate, prefill and the
+        scheduler's guard.
 
-        ``make_call(rung)`` returns a zero-arg callable for that rung.
+        ``make_call(rung)`` returns a zero-arg callable for that rung;
+        ``kind`` names the call in the ``guard.call`` span.
         """
         deadline = (self.policy.deadline_s if deadline_s is None
                     else deadline_s)
@@ -240,7 +257,8 @@ class ResilientEngine:
                 if attempt > 0:
                     FALLBACK_COUNTS[f"retry:{rung}"] += 1
                 try:
-                    out = self._run_rung(rung, make_call(rung))
+                    out = self._run_rung(rung, make_call(rung), kind,
+                                         attempt)
                     self.last_rung = rung
                     return out
                 except jax.errors.JaxRuntimeError as e:
@@ -266,7 +284,8 @@ class ResilientEngine:
                                      max_len=max_len,
                                      temperature=temperature, key=key,
                                      embeds=embeds)
-        return self._with_ladder(make_call, deadline_s=deadline_s)
+        return self._with_ladder(make_call, "generate",
+                                 deadline_s=deadline_s)
 
     def prefill(self, batch, caches, *, deadline_s: float | None = None):
         def make_call(rung):
@@ -274,7 +293,8 @@ class ResilientEngine:
             return lambda: _prefill(cfg, self.mesh, self.state.params,
                                     self.state.lut, batch, caches,
                                     residency=self.residency)
-        return self._with_ladder(make_call, deadline_s=deadline_s)
+        return self._with_ladder(make_call, "prefill",
+                                 deadline_s=deadline_s)
 
     def _guard(self, call, kind: str):
         """Scheduler guard hook: run one jitted engine call (``call(cfg)``,
@@ -285,7 +305,7 @@ class ResilientEngine:
         they walk the same ladder, so a probe only reports a subset faulty
         when no rung can serve it — exactly the culprit criterion."""
         return self._with_ladder(
-            lambda rung: (lambda: call(self._rung_cfg(rung))),
+            lambda rung: (lambda: call(self._rung_cfg(rung))), kind,
             deadline_s=None)
 
     def scheduler(self, **engine_kw):
@@ -331,7 +351,7 @@ class ResilientEngine:
                        if self.verify_report else None),
             "invariants": (self.invariant_report.summary()
                            if self.invariant_report else None),
-            "recent_errors": self._history[-8:],
+            "recent_errors": list(self._history),
         }
         if self.residency is not None:
             out["residency"] = self.residency.snapshot()

@@ -74,6 +74,13 @@ batch shapes.
 ``ResilientEngine.scheduler()`` wraps every jitted step in the
 retry/deadline/degradation ladder via the ``guard`` hook — see
 serve/resilience.py and docs/serving.md.
+
+Tracing: each part of a step runs inside a ``jax.profiler.TraceAnnotation``
+span — ``serve.step`` ⊃ ``serve.admit`` (⊃ ``serve.prefill``,
+``serve.insert``) and ``serve.decode`` (⊃ ``serve.decode.inputs``, the
+guarded call, ``serve.decode.retire``, ``serve.quarantine``) — so a
+profile puts each device-idle gap under what the serving thread was doing.
+Outside a profile a span costs about a microsecond; see docs/serving.md.
 """
 from __future__ import annotations
 
@@ -86,6 +93,7 @@ from typing import Any, List, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.models import lm as LM
 from repro.serve import engine as _engine
@@ -263,7 +271,8 @@ class Engine:
         residency, the manager's fetch/hit counters and the module-wide
         ``RESIDENCY_COUNTS`` probe reset too."""
         self.stats = {"admitted": 0, "joined_mid_decode": 0,
-                      "occupancy": [], "shed": 0, "expired": 0,
+                      "occupancy_steps": 0, "occupancy_sum": 0,
+                      "occupancy_max": 0, "shed": 0, "expired": 0,
                       "preempted": 0, "quarantined": 0, "resumed": 0,
                       "queue_peak": 0, "pressure_refused": 0,
                       "pressure_preempted": 0}
@@ -334,17 +343,22 @@ class Engine:
         attached it runs first — the step boundary is the only fence
         where no jitted call is in flight, so capacity trims / page
         retirement (which reshape traced arrays) are safe here."""
-        if self.governor is not None:
-            self.governor.on_step(self)
-        done = self._expire()
-        done.extend(self._admit())
-        occ = [i for i, s in enumerate(self._slots) if s is not None]
-        self.stats["occupancy"].append(len(occ))
-        if occ:
-            done.extend(self._decode_tick())
-        self.steps += 1
-        self.completions.extend(done)
-        return done
+        with TraceAnnotation("serve.step", step=self.steps):
+            if self.governor is not None:
+                self.governor.on_step(self)
+            done = self._expire()
+            done.extend(self._admit())
+            occ = sum(s is not None for s in self._slots)
+            self.stats["occupancy_steps"] += 1
+            self.stats["occupancy_sum"] += occ
+            self.stats["occupancy_max"] = max(self.stats["occupancy_max"],
+                                              occ)
+            if occ:
+                with TraceAnnotation("serve.decode", rows=occ):
+                    done.extend(self._decode_tick())
+            self.steps += 1
+            self.completions.extend(done)
+            return done
 
     def drain(self, max_steps: int = 100_000) -> List[Completion]:
         """Step until the queue and all slots are empty; returns the
@@ -365,7 +379,6 @@ class Engine:
         return out
 
     def health(self) -> dict:
-        occ = self.stats["occupancy"]
         out = {
             "steps": self.steps,
             "queued": len(self._queue),
@@ -373,8 +386,9 @@ class Engine:
             "occupied": sum(s is not None for s in self._slots),
             "admitted": self.stats["admitted"],
             "joined_mid_decode": self.stats["joined_mid_decode"],
-            "occupancy_mean": float(np.mean(occ)) if occ else 0.0,
-            "occupancy_max": int(np.max(occ)) if occ else 0,
+            "occupancy_mean": (self.stats["occupancy_sum"]
+                               / max(self.stats["occupancy_steps"], 1)),
+            "occupancy_max": self.stats["occupancy_max"],
             "completed": len(self.completions),
             "free_pages": len(self.pool.free_pages),
             "shed": self.stats["shed"],
@@ -546,69 +560,78 @@ class Engine:
             p = self._queue.popleft()
             req = p.req
             resume = bool(p.out)
-            toks = (np.concatenate([req.tokens,
-                                    np.asarray(p.out[:-1], np.int32)])
-                    if resume else req.tokens)
-            try:
-                tok0, frag = self._prefill(toks)
-            except _FAULTS as e:
-                FALLBACK_COUNTS["quarantine"] += 1
-                self.stats["quarantined"] += 1
-                done.append(self._completion(
-                    req.rid, req.tokens, p.out, "refused", p.submitted_step,
-                    resumed=p.resumed, error=repr(e)))
-                continue
-            self.stats["admitted"] += 1
-            if resume:
-                self.stats["resumed"] += 1
-            if any(s is not None for s in self._slots):
-                self.stats["joined_mid_decode"] += 1
-            if not resume:
-                if req.max_new == 1 or (req.eos_id is not None
-                                        and tok0 == req.eos_id):
+            with TraceAnnotation("serve.admit", rid=req.rid,
+                                 prompt_len=len(req.tokens),
+                                 resume=p.resumed):
+                toks = (np.concatenate([req.tokens,
+                                        np.asarray(p.out[:-1], np.int32)])
+                        if resume else req.tokens)
+                try:
+                    with TraceAnnotation("serve.prefill"):
+                        tok0, frag = self._prefill(toks)
+                except _FAULTS as e:
+                    FALLBACK_COUNTS["quarantine"] += 1
+                    self.stats["quarantined"] += 1
                     done.append(self._completion(
-                        req.rid, req.tokens, [tok0],
-                        "eos" if (req.eos_id is not None
-                                  and tok0 == req.eos_id)
-                        else "max_new", p.submitted_step))
+                        req.rid, req.tokens, p.out, "refused",
+                        p.submitted_step, resumed=p.resumed, error=repr(e)))
                     continue
-                out = [tok0]
-            else:
-                out = list(p.out)      # resume: discard the probe token
-            slot = free[0]
-            try:
-                self.pool.alloc(slot)
-            except PoolExhausted:
-                # pressure surfaced at the alloc seam itself (injected
-                # fault, or raced reclaim): requeue at the head and retry
-                # next tick — prefill is pure, so nothing is lost
-                self._queue.appendleft(p)
-                break
-            self.pool.insert(frag, slot)
-            self._slots[slot] = _Slot(
-                req=req, out=out, pos=len(req.tokens) + len(out) - 1,
-                key=np.asarray(jax.random.PRNGKey(req.seed), np.uint32),
-                submitted_step=p.submitted_step, submit_time=p.submit_time,
-                resumed=p.resumed)
+                self.stats["admitted"] += 1
+                if resume:
+                    self.stats["resumed"] += 1
+                if any(s is not None for s in self._slots):
+                    self.stats["joined_mid_decode"] += 1
+                if not resume:
+                    if req.max_new == 1 or (req.eos_id is not None
+                                            and tok0 == req.eos_id):
+                        done.append(self._completion(
+                            req.rid, req.tokens, [tok0],
+                            "eos" if (req.eos_id is not None
+                                      and tok0 == req.eos_id)
+                            else "max_new", p.submitted_step))
+                        continue
+                    out = [tok0]
+                else:
+                    out = list(p.out)      # resume: discard the probe token
+                slot = free[0]
+                with TraceAnnotation("serve.insert"):
+                    try:
+                        self.pool.alloc(slot)
+                    except PoolExhausted:
+                        # pressure surfaced at the alloc seam itself
+                        # (injected fault, or raced reclaim): requeue at
+                        # the head and retry next tick — prefill is pure,
+                        # so nothing is lost
+                        self._queue.appendleft(p)
+                        break
+                    self.pool.insert(frag, slot)
+                self._slots[slot] = _Slot(
+                    req=req, out=out, pos=len(req.tokens) + len(out) - 1,
+                    key=np.asarray(jax.random.PRNGKey(req.seed), np.uint32),
+                    submitted_step=p.submitted_step,
+                    submit_time=p.submit_time, resumed=p.resumed)
         return done
 
     # -- decode --------------------------------------------------------
     def _decode_tick(self) -> List[Completion]:
-        b = self.pool.n_slots
-        tok = np.zeros((b, 1), np.int32)
-        pos = np.zeros((b,), np.int32)
-        active = np.zeros((b,), bool)
-        temp = np.zeros((b,), np.float32)
-        keys = np.zeros((b, 2), np.uint32)
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            tok[i, 0] = s.out[-1]
-            pos[i] = s.pos
-            active[i] = True
-            temp[i] = s.req.temperature
-            keys[i] = s.key
-        pt = jnp.asarray(self.pool.page_table)
+        with TraceAnnotation("serve.decode.inputs"):
+            b = self.pool.n_slots
+            tok = np.zeros((b, 1), np.int32)
+            pos = np.zeros((b,), np.int32)
+            active = np.zeros((b,), bool)
+            temp = np.zeros((b,), np.float32)
+            keys = np.zeros((b, 2), np.uint32)
+            for i, s in enumerate(self._slots):
+                if s is None:
+                    continue
+                tok[i, 0] = s.out[-1]
+                pos[i] = s.pos
+                active[i] = True
+                temp[i] = s.req.temperature
+                keys[i] = s.key
+            pt = jnp.asarray(self.pool.page_table)
+            tok, pos = jnp.asarray(tok), jnp.asarray(pos)
+            temp, keys = jnp.asarray(temp), jnp.asarray(keys)
 
         def call_with(mask):
             mgr = getattr(self.ctx, "residency", None)
@@ -626,44 +649,43 @@ class Engine:
                     def launch(dp):
                         pages_, nxt_, routing = _res._tiered_generate_step(
                             cfg, self.ctx.mesh, self.pool.page_size, dp,
-                            self.ctx.lut, self.pool.pages, pt,
-                            jnp.asarray(tok), jnp.asarray(pos),
-                            jnp.asarray(mask), jnp.asarray(temp),
-                            jnp.asarray(keys))
+                            self.ctx.lut, self.pool.pages, pt, tok, pos,
+                            jnp.asarray(mask), temp, keys)
                         return (pages_, nxt_), routing
 
                     return mgr.run(launch, active=mask)
                 return _generate_step(
                     cfg, self.ctx.mesh, self.pool.page_size, self.params,
-                    self.ctx.lut, self.pool.pages, pt, jnp.asarray(tok),
-                    jnp.asarray(pos), jnp.asarray(mask), jnp.asarray(temp),
-                    jnp.asarray(keys))
+                    self.ctx.lut, self.pool.pages, pt, tok, pos,
+                    jnp.asarray(mask), temp, keys)
             return call
 
         try:
             pages, nxt = self.guard(call_with(active), "decode")
         except _FAULTS as e:
-            return self._quarantine(active, call_with, e)
-        self.pool.pages = pages
-        nxt = np.asarray(nxt)
+            with TraceAnnotation("serve.quarantine"):
+                return self._quarantine(active, call_with, e)
 
-        done: List[Completion] = []
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            t = int(nxt[i])
-            s.out.append(t)
-            s.pos += 1
-            if len(s.out) >= s.req.max_new or (s.req.eos_id is not None
-                                               and t == s.req.eos_id):
-                reason = ("eos" if s.req.eos_id is not None
-                          and t == s.req.eos_id else "max_new")
-                done.append(self._completion(s.rid, s.prompt, s.out,
-                                             reason, s.submitted_step,
-                                             resumed=s.resumed))
-                self.pool.free(i)
-                self._slots[i] = None
-        return done
+        with TraceAnnotation("serve.decode.retire"):
+            self.pool.pages = pages
+            nxt = np.asarray(nxt)
+            done: List[Completion] = []
+            for i, s in enumerate(self._slots):
+                if s is None:
+                    continue
+                t = int(nxt[i])
+                s.out.append(t)
+                s.pos += 1
+                if len(s.out) >= s.req.max_new or (
+                        s.req.eos_id is not None and t == s.req.eos_id):
+                    reason = ("eos" if s.req.eos_id is not None
+                              and t == s.req.eos_id else "max_new")
+                    done.append(self._completion(s.rid, s.prompt, s.out,
+                                                 reason, s.submitted_step,
+                                                 resumed=s.resumed))
+                    self.pool.free(i)
+                    self._slots[i] = None
+            return done
 
     def _quarantine(self, active, call_with, exc) -> List[Completion]:
         """Bisect the active slots to isolate the poisoned request(s).

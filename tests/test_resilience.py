@@ -206,6 +206,24 @@ def test_ladder_exhausted_refuses_with_diagnostics(served):
     assert all(r == "fused" for r, _, _ in ei.value.errors)
 
 
+
+def test_health_reports_the_newest_eight_failures(served):
+    """The failure history is bounded: a walk of 12 failed attempts
+    leaves the newest 8 in ``health()['recent_errors']``."""
+    cfg, st, toks, _ = served
+    eng = ResilientEngine(
+        cfg, st, policy=ResiliencePolicy(max_retries=11, ladder=("fused",)))
+    inj = FaultInjector()
+    orig = resilience._generate
+    resilience._generate = inj.failing(orig, times=12)
+    try:
+        with pytest.raises(ServeRefused) as ei:
+            eng.generate(toks, max_new=4)
+    finally:
+        resilience._generate = orig
+    assert len(ei.value.errors) == 12
+    assert eng.health()["recent_errors"] == ei.value.errors[-8:]
+
 def test_deadline_expires_mid_ladder(served):
     cfg, st, toks, _ = served
     eng = ResilientEngine(

@@ -158,6 +158,22 @@ def test_completion_frees_slot_and_queue_refills(served):
             err_msg=f"request {i}: stale KV after page reuse?")
 
 
+
+def test_occupancy_counts_every_step(served):
+    """``health()`` occupancy is over every step since ``reset_stats``:
+    max_new 3 and 5 on two slots occupy 2, 2, 1, 1 slots."""
+    cfg, st, ctx = served
+    eng = Engine(ctx, st.params, n_slots=2, max_len=16)
+    for i, (p, n) in enumerate(zip(_prompts(cfg, 2, seed=11), (3, 5))):
+        eng.submit(Request(tokens=p, max_new=n, rid=i))
+    eng.drain()
+    h = eng.health()
+    assert (h["steps"], h["occupancy_mean"], h["occupancy_max"]) == \
+        (4, 1.5, 2)
+    eng.reset_stats()
+    h = eng.health()
+    assert (h["occupancy_mean"], h["occupancy_max"]) == (0.0, 0)
+
 def test_page_reuse_no_stale_kv(served):
     """Serve the same prompt before and after other tenants churned
     through the pool's pages (LIFO reuse): outputs must be identical."""
