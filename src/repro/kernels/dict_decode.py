@@ -12,9 +12,17 @@ an int32 — and builds every lookup from in-vreg lane gathers
 (``take_along_axis`` over 128 lanes):
 
   * dictionary slots: the LUT is resident as an (R, 128) word array.  The
-    kernel sweeps its R rows; row r broadcasts across the codes' sublanes,
-    a lane gather by ``code & 127`` reads it, and a select keeps it where
-    ``code >> 7 == r``.  R ≤ 512 for a full 64k dictionary (256 KiB).
+    kernel takes one code vreg (8 block rows × 128 slots) at a time, in a
+    loop over the vregs of a VMEM work ref, splits it once into
+    ``hi = code >> 7`` and ``lo = code & 127``, and sweeps all R rows for
+    it with its accumulator the only loop carry: row r broadcasts across
+    the sublanes, a lane gather by ``lo`` reads it, and a select keeps it
+    where ``hi == r``.  Every gather of the sweep shares ``lo``, so its
+    lane pattern is set once per loop step, not once per row, and the
+    accumulator stays in a register.  A loop step takes up to 128 rows: a
+    lane gather's result comes back from the XLU long after it is issued,
+    so a step issues many gathers before it waits on the first.  R ≤ 512
+    for a full 64k dictionary (256 KiB).
   * literal slots: a block's escape grams are packed as ``cap`` words;
     ``rank = cumsum(is_escape) - 1`` (a triangular MXU matmul per 128-lane
     chunk — Mosaic has no cumsum) selects the row by the same
@@ -25,6 +33,7 @@ The decoded words are bit-identical to ``ref.dict_decode``'s bytes.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +43,8 @@ from repro.core.codec import ESCAPE
 
 DEFAULT_CHUNK = 16
 LANES = 128
-_LUT_ROWS_PER_STEP = 8
+_SUBLANES = 8
+_SWEEP_ROWS = 128
 
 
 # ---------------------------------------------------------------------------
@@ -63,7 +73,7 @@ def lut_words(lut: jax.Array) -> jax.Array:
     """(n_codes, S) uint8 LUT → (R, 128) int32, R a multiple of 8; padded
     rows decode to 0 and are never selected by a valid code."""
     w = to_words(lut)
-    per = LANES * _LUT_ROWS_PER_STEP
+    per = LANES * _SUBLANES
     w = jnp.pad(w, (0, (-w.shape[0]) % per))
     return w.reshape(-1, LANES)
 
@@ -105,34 +115,65 @@ def _escape_rank(is_esc, width):
     return out
 
 
-def decode_words(codes, lit_words, lut_ref):
+def _lut_sweep(code, lut_ref):
+    """Dictionary words of one code vreg: every LUT row in turn, the
+    vreg's accumulator the only loop carry.  ``hi``/``lo`` are taken once,
+    so each row's lane gather reuses the vreg's lane pattern.  A loop step
+    takes ``_SWEEP_ROWS`` rows, or the largest power of two that divides
+    R if that is fewer."""
+    hi, lo = code >> 7, code & (LANES - 1)
+    n_rows = lut_ref.shape[0]
+    step = math.gcd(n_rows, _SWEEP_ROWS)
+
+    def lut_step(i, acc):
+        blk = lut_ref[pl.ds(i * step, step), :]
+        for t in range(step):
+            row = jnp.broadcast_to(blk[t:t + 1, :], (code.shape[0], LANES))
+            acc = jnp.where(hi == i * step + t,
+                            jnp.take_along_axis(row, lo, axis=1), acc)
+        return acc
+
+    return jax.lax.fori_loop(0, n_rows // step, lut_step, jnp.zeros_like(code))
+
+
+def _lut_sweeps(work_ref, lut_ref, height, width):
+    """Replace each (height, width) code vreg of ``work_ref`` by its
+    dictionary words.  The vregs take turns in a loop, not unrolled, so
+    the row sweep's code appears once in the kernel and compiles about as
+    fast as an 8-row sweep."""
+    rows, slots = work_ref.shape
+    n_chunks = slots // width
+
+    def one_vreg(j, carry):
+        g = pl.multiple_of((j // n_chunks) * height, height)
+        c = pl.multiple_of((j % n_chunks) * width, width)
+        idx = (pl.ds(g, height), pl.ds(c, width))
+        work_ref[idx] = _lut_sweep(work_ref[idx], lut_ref)
+        return carry
+
+    jax.lax.fori_loop(0, rows // height * n_chunks, one_vreg, 0)
+
+
+def decode_words(codes, lit_words, lut_ref, work_ref):
     """Decode one group of blocks to int32 words.
 
     codes: (rows, W) integer codes, one block per row; lit_words:
     (rows, cap') int32, cap' a multiple of 128; lut_ref: the resident
-    (R, 128) int32 LUT ref.  Returns (rows, W) int32 words."""
+    (R, 128) int32 LUT ref; work_ref: a (rows, W) int32 VMEM ref the
+    dictionary sweep overwrites.  Returns (rows, W) int32 words."""
     codes = codes.astype(jnp.int32)
     rows, slots = codes.shape
     # 128-lane chunks; a row that is no whole number of them (small
     # shapes, interpret mode only) is one chunk
     width = LANES if slots % LANES == 0 else slots
+    # code vregs: 8-row sublane groups of each chunk (all rows if they
+    # are no whole number of groups)
+    height = _SUBLANES if rows % _SUBLANES == 0 else rows
     code_c = _lane_chunks(codes, width)
     esc_c = [c == ESCAPE for c in code_c]
-    hi_c = [c >> 7 for c in code_c]
-    lo_c = [c & (LANES - 1) for c in code_c]
-
-    def lut_step(i, acc):
-        blk = lut_ref[pl.ds(i * _LUT_ROWS_PER_STEP, _LUT_ROWS_PER_STEP), :]
-        for t in range(_LUT_ROWS_PER_STEP):
-            r = i * _LUT_ROWS_PER_STEP + t
-            row = jnp.broadcast_to(blk[t:t + 1, :], (rows, LANES))
-            acc = [jnp.where(h == r, jnp.take_along_axis(row, lo, axis=1), a)
-                   for a, h, lo in zip(acc, hi_c, lo_c)]
-        return acc
-
-    n_steps = lut_ref.shape[0] // _LUT_ROWS_PER_STEP
-    from_dict = jax.lax.fori_loop(
-        0, n_steps, lut_step, [jnp.zeros_like(c) for c in code_c])
+    work_ref[...] = codes
+    _lut_sweeps(work_ref, lut_ref, height, width)
+    from_dict = _lane_chunks(work_ref[...], width)
 
     rank_c = _escape_rank(esc_c, width)
     from_lit = [jnp.zeros_like(c) for c in code_c]
@@ -151,7 +192,7 @@ def decode_words(codes, lit_words, lut_ref):
 # ---------------------------------------------------------------------------
 
 def _kernel(codes_ref, lit_ref, lut_ref, o_ref):
-    o_ref[...] = decode_words(codes_ref[...], lit_ref[...], lut_ref)
+    o_ref[...] = decode_words(codes_ref[...], lit_ref[...], lut_ref, o_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

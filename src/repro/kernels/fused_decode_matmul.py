@@ -59,7 +59,7 @@ from .dict_decode import decode_words, literal_words, lut_words
 DEFAULT_BM = 128
 
 
-def _decode_tile(codes_ref, lit_ref, lut_ref, tn, tk, s):
+def _decode_tile(codes_ref, lit_ref, lut_ref, work_ref, tn, tk, s):
     """Decode one (tile_n, tile_k) weight tile from its compressed blocks.
     The uint8 values live only in VMEM.
 
@@ -71,7 +71,7 @@ def _decode_tile(codes_ref, lit_ref, lut_ref, tn, tk, s):
     in-kernel byte interleave or cross-sublane reshape is needed."""
     codes = codes_ref[...].reshape(codes_ref.shape[-2:])  # (bpt, W)
     lits = lit_ref[...].reshape(lit_ref.shape[-2:])       # (bpt, cap')
-    words = decode_words(codes, lits, lut_ref)            # (bpt, W) int32
+    words = decode_words(codes, lits, lut_ref, work_ref)  # (bpt, W) int32
     row_words = tk // s
     rows = []
     for g in range(words.shape[1] // row_words):
@@ -128,7 +128,7 @@ def _unpermute_cols(y, tile_n, rpb):
 
 
 def _kernel(x_ref, codes_ref, lit_ref, lut_ref, scale_ref, zero_ref, o_ref,
-            acc_ref, sumx_ref, *, s):
+            acc_ref, sumx_ref, work_ref, *, s):
     g_idx = pl.program_id(2)
     k_idx = pl.program_id(3)
     ng = pl.num_programs(2)
@@ -140,7 +140,7 @@ def _kernel(x_ref, codes_ref, lit_ref, lut_ref, scale_ref, zero_ref, o_ref,
         sumx_ref[...] = jnp.zeros_like(sumx_ref)
 
     tn, tk = scale_ref.shape[0], x_ref.shape[1]
-    q = _decode_tile(codes_ref, lit_ref, lut_ref, tn, tk, s)
+    q = _decode_tile(codes_ref, lit_ref, lut_ref, work_ref, tn, tk, s)
     _accumulate(x_ref[...], q, acc_ref, sumx_ref)
 
     @pl.when((g_idx == ng - 1) & (k_idx == nk - 1))
@@ -216,7 +216,8 @@ def fused_decode_matmul(x: jax.Array, codes: jax.Array, literals: jax.Array,
         out_specs=pl.BlockSpec((bm, tile_n), lambda i, j, g, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, tile_n), jnp.float32),
-                        pltpu.VMEM((bm, 1), jnp.float32)],
+                        pltpu.VMEM((bm, 1), jnp.float32),
+                        pltpu.VMEM((bpt, slots), jnp.int32)],
         interpret=interpret,
     )(_permute_x(x, tile_k, s), codes, lits, lutw,
       _permute_rows(scale, tile_n, rpb), _permute_rows(zero, tile_n, rpb))
@@ -224,7 +225,7 @@ def fused_decode_matmul(x: jax.Array, codes: jax.Array, literals: jax.Array,
 
 
 def _grouped_kernel(x_ref, codes_ref, lit_ref, lut_ref, scale_ref, zero_ref,
-                    o_ref, acc_ref, sumx_ref, *, s):
+                    o_ref, acc_ref, sumx_ref, work_ref, *, s):
     k_idx = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -234,7 +235,7 @@ def _grouped_kernel(x_ref, codes_ref, lit_ref, lut_ref, scale_ref, zero_ref,
         sumx_ref[...] = jnp.zeros_like(sumx_ref)
 
     tn, tk = scale_ref.shape[1], x_ref.shape[2]
-    q = _decode_tile(codes_ref, lit_ref, lut_ref, tn, tk, s)
+    q = _decode_tile(codes_ref, lit_ref, lut_ref, work_ref, tn, tk, s)
     _accumulate(x_ref[...].reshape(x_ref.shape[-2:]), q, acc_ref, sumx_ref)
 
     @pl.when(k_idx == nk - 1)
@@ -305,7 +306,8 @@ def grouped_fused_decode_matmul(x: jax.Array, codes: jax.Array,
                                lambda ei, i, j, k: (ei, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, tile_n), jnp.float32),
-                        pltpu.VMEM((bm, 1), jnp.float32)],
+                        pltpu.VMEM((bm, 1), jnp.float32),
+                        pltpu.VMEM((bpt, slots), jnp.int32)],
         interpret=interpret,
     )(_permute_x(x, tile_k, s), codes, lits, lutw,
       _permute_rows(scale, tile_n, rpb), _permute_rows(zero, tile_n, rpb))

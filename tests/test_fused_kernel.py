@@ -17,6 +17,7 @@ from repro.kernels import ops, ref
 import importlib
 
 fdm_kernel = importlib.import_module("repro.kernels.fused_decode_matmul")
+dd_kernel = importlib.import_module("repro.kernels.dict_decode")
 
 
 def _packed_pair(rng, n, k, structured=True, table=None):
@@ -204,3 +205,66 @@ def test_dict_decode_prime_block_counts(nblocks, rng):
     out_pal = ops.dict_decode(bc.codes, bc.literals, bc.nlit, bc.lut,
                               impl="pallas_interpret")
     np.testing.assert_array_equal(np.asarray(out_ref), np.asarray(out_pal))
+
+
+# ---------------------------------------------------------------------------
+# full 64k dictionary: every LUT row of the per-vreg sweep
+# ---------------------------------------------------------------------------
+
+def _full_lut_planes(rng, nb, slots, n_codes=codec.ESCAPE):
+    """Codes over an ``n_codes``-entry dictionary (R = 512 LUT rows when
+    full), mixed in every code vreg: LUT row boundaries (127/128,
+    1023/1024), the last row's codes, ESCAPE and uniform codes; literal
+    words for every escape."""
+    lut = rng.integers(0, 256, size=(n_codes, 4)).astype(np.uint8)
+    kind = rng.integers(0, 4, size=(nb, slots))
+    codes = np.where(
+        kind == 0, rng.choice([127, 128, 1023, 1024], size=(nb, slots)),
+        np.where(kind == 1, rng.integers(n_codes - 127, n_codes,
+                                         size=(nb, slots)),
+                 np.where(kind == 2, codec.ESCAPE,
+                          rng.integers(0, n_codes, size=(nb, slots)))))
+    codes = codes.astype(np.uint16)
+    literals = rng.integers(0, 256, size=(nb, slots, 4)).astype(np.uint8)
+    nlit = (codes == codec.ESCAPE).sum(axis=1).astype(np.int32)
+    return (jnp.asarray(codes), jnp.asarray(literals), jnp.asarray(nlit),
+            jnp.asarray(lut))
+
+
+@pytest.mark.parametrize("nb,slots,n_codes", [
+    (32, 1024, codec.ESCAPE),  # 16-row chunks: 2 sublane groups x 8 lanes
+    (16, 96, codec.ESCAPE),    # slots % 128 != 0: one chunk, 2 groups
+    (6, 96, codec.ESCAPE),     # one chunk, rows no whole sublane group
+    (16, 256, 6000),           # R = 48: the sweep steps 16 rows at a time
+])
+def test_dict_decode_full_lut_bitexact(nb, slots, n_codes, rng):
+    codes, literals, nlit, lut = _full_lut_planes(rng, nb, slots, n_codes)
+    out_ref = ref.dict_decode(codes, literals, nlit, lut)
+    out_pal = dd_kernel.dict_decode(codes, literals, nlit, lut,
+                                    interpret=True)
+    np.testing.assert_array_equal(np.asarray(out_pal), np.asarray(out_ref))
+    c = np.asarray(codes)
+    hits = np.asarray(out_pal).reshape(nb, slots, 4)[c != codec.ESCAPE]
+    np.testing.assert_array_equal(hits, np.asarray(lut)[c[c != codec.ESCAPE]])
+
+
+@pytest.mark.parametrize("n,k,tile_n,tile_k,slots", [
+    (128, 512, 128, 512, 1024),  # internlm2's tile: bpt 16 = 2 x 8 vregs
+    (32, 96, 16, 96, 96),        # one chunk of 96 slots, bpt 4
+])
+def test_fused_full_lut_bitexact(n, k, tile_n, tile_k, slots, rng):
+    """Integer activations and power-of-two scales keep every sum exact,
+    so the kernel must match the oracle bit for bit."""
+    m = 8
+    nb = n * k // (slots * 4)
+    codes, literals, nlit, lut = _full_lut_planes(rng, nb, slots)
+    x = jnp.asarray(rng.integers(-8, 9, size=(m, k)).astype(np.float32))
+    scale = jnp.asarray(2.0 ** -rng.integers(4, 9, size=(n, 1)),
+                        jnp.float32)
+    zero = jnp.asarray(rng.integers(0, 256, size=(n, 1)), jnp.float32)
+    kw = dict(shape=(n, k), tile_n=tile_n, tile_k=tile_k)
+    y_ref = ref.fused_decode_matmul(x, codes, literals, nlit, lut, scale,
+                                    zero, **kw)
+    y_pal = fdm_kernel.fused_decode_matmul(x, codes, literals, lut, scale,
+                                           zero, bm=m, interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(y_pal), np.asarray(y_ref))
